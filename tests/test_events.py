@@ -219,13 +219,6 @@ def test_chrome_trace_schema():
     assert recs[0]["spans"][0]["name"] == "pack"
 
 
-def test_device_trace_dir_linked():
-    fr = FlightRecorder()
-    fr.device_trace_dir = "/tmp/jax-trace"
-    fr.record_cycle({"cycle": 1}, [])
-    assert fr.chrome_trace()["otherData"]["device_trace_dir"] == "/tmp/jax-trace"
-
-
 def test_trace_span_intervals_are_ordered_wall_times():
     t = Trace()
     with t:

@@ -1,13 +1,18 @@
 """Cycle cost-attribution profiler (utils/profiler.py + tracing upgrades):
 hierarchical spans, the derived phase set (a new phase can't silently land
 in `other`), the continuous ring, SLO burn tracking, the /debug/profile
-route, nested Chrome-trace slices, and the two tier-1 gates — attribution
+route, nested Chrome-trace slices, the spans as profiler annotations,
+collector pauses, the bind API time, and the two tier-1 gates — attribution
 coverage ≥ 0.9 and span+ring overhead < 2% on a steady-state scenario."""
 
 from __future__ import annotations
 
+import gc
+import glob
 import json
+import os
 import threading
+import time
 import urllib.request
 
 import pytest
@@ -23,11 +28,14 @@ from tpu_scheduler.utils.profiler import (
     ProfileRing,
     ReplicaProfileRegistry,
     build_tree,
+    gc_pauses,
+    install_gc_listener,
     record_transfer,
     span_cost_estimate,
     tier_of,
     transfer_bytes_total,
 )
+from tpu_scheduler.utils import tracing
 from tpu_scheduler.utils.tracing import Trace, base_name, span
 
 
@@ -443,3 +451,183 @@ def test_profiled_scenario_is_deterministic_in_census():
 def test_span_cost_microbench_is_sane():
     per = span_cost_estimate(n=500)
     assert 0 < per < 50e-6  # a span is microseconds, not milliseconds
+
+
+# --- spans on the profiler's clock -------------------------------------------
+
+
+def _host_events(trace_dir: str) -> list[tuple[str, int, int]]:
+    import jax
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    return [
+        (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    ]
+
+
+def test_cycle_phases_land_in_the_profiler_trace(tmp_path):
+    """Under a profiler session every depth-0 phase of a cycle is a
+    ``sched:<phase>`` host event inside the cycle's interval; recorded sums
+    (``bind/post``) stay out of the profiler trace."""
+    import jax
+
+    snap = synth_cluster(n_nodes=16, n_pending=64, n_bound=8, seed=3)
+    api = FakeApiServer()
+    api.load(snap.nodes, snap.pods)
+    s = Scheduler(api, NativeBackend(), requeue_seconds=0.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.cycle"):
+            m = s.run_cycle()
+    finally:
+        jax.profiler.stop_trace()
+    assert m.bound > 0
+    events = _host_events(str(tmp_path))
+    ((_, lo, hi),) = [e for e in events if e[0] == "test.cycle"]
+    annotated = set()
+    for name, start, end in events:
+        if name.startswith("sched:") and name != "sched:gc":
+            assert lo <= start <= end <= hi, name
+            annotated.add(name[len("sched:"):])
+    paths = {sp["name"] for sp in s.recorder.cycles(1)[0]["spans"]}
+    phases = {p for p in paths if "/" not in p}
+    assert {"sync", "queue", "pack", "solve", "bind"} <= phases
+    assert phases <= annotated
+    assert "bind/post" in paths and "bind/post" not in annotated
+
+
+class _CountingAnnotation:
+    enabled = False
+    made: list = []
+
+    @staticmethod
+    def is_enabled() -> bool:
+        return _CountingAnnotation.enabled
+
+    def __init__(self, name, **_metadata):
+        _CountingAnnotation.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+def test_no_annotation_is_made_without_a_session(monkeypatch):
+    monkeypatch.setattr(tracing, "_annotation_cls", _CountingAnnotation)
+    monkeypatch.setattr(_CountingAnnotation, "made", [])
+    t = Trace()
+    with t:
+        with span("solve"):
+            with span("round[00]"):
+                pass
+    assert _CountingAnnotation.made == []
+    monkeypatch.setattr(_CountingAnnotation, "enabled", True)
+    with t:
+        with span("solve"):
+            with span("round[00]"):
+                pass
+        t.record("compile", 0.1)
+    assert _CountingAnnotation.made == ["sched:solve", "sched:solve/round[00]"]
+
+
+# --- collector pauses --------------------------------------------------------
+
+
+def test_gc_pause_is_recorded_and_folded_into_the_histogram():
+    s = Scheduler(FakeApiServer(), NativeBackend(), requeue_seconds=0.0)
+    t0 = time.perf_counter()
+    gc.collect()
+    mine = [p for p in gc_pauses() if p[0] >= t0]
+    assert any(gen == 2 and seconds > 0 for _, seconds, gen in mine), mine
+    key = 'scheduler_gc_pause_seconds_count{generation="2"}'
+
+    def gen2_count() -> float:
+        for line in s.metrics.to_prometheus().splitlines():
+            if line.startswith(key):
+                return float(line.split(" ")[1])
+        return 0.0
+
+    s.run_cycle()
+    first = gen2_count()
+    assert first >= 1
+    # Folded once: the next cycle adds only the full collections after the
+    # last pause already folded.
+    folded = s._gc_folded
+    s.run_cycle()
+    assert gen2_count() == first + sum(1 for t, _, gen in gc_pauses() if gen == 2 and t > folded)
+
+
+def test_gc_under_the_registry_lock_returns():
+    """A collection can fire while its thread holds MetricsRegistry._lock;
+    the listener takes no lock, so it cannot deadlock there."""
+    install_gc_listener()
+    reg = MetricsRegistry()
+    done = threading.Event()
+
+    def hold_and_collect():
+        with reg._lock:
+            gc.collect()
+        done.set()
+
+    th = threading.Thread(target=hold_and_collect, daemon=True)
+    th.start()
+    assert done.wait(timeout=30)
+    th.join(timeout=5)
+    assert not th.is_alive()
+
+
+# --- the bind API time -------------------------------------------------------
+
+
+class _SlowBindApi(FakeApiServer):
+    def __init__(self, delay: float):
+        super().__init__()
+        self.delay = delay
+
+    def create_binding(self, namespace, pod_name, target):
+        time.sleep(self.delay)
+        super().create_binding(namespace, pod_name, target)
+
+
+def _slow_bind_cluster(delay: float, n: int) -> _SlowBindApi:
+    api = _SlowBindApi(delay)
+    api.load(
+        nodes=[make_node(f"n{i}", cpu="8", memory="16Gi") for i in range(4)],
+        pods=[make_pod(f"p{i}", cpu="100m", memory="64Mi") for i in range(n)],
+    )
+    return api
+
+
+def test_bind_post_is_the_api_time_under_bind():
+    delay, n = 0.005, 10
+    s = Scheduler(_slow_bind_cluster(delay, n), NativeBackend(), requeue_seconds=0.0)
+    m = s.run_cycle()
+    assert m.bound == n
+    spans = s.recorder.cycles(1)[0]["spans"]
+    post = sum(sp["end"] - sp["start"] for sp in spans if sp["name"] == "bind/post")
+    bind = sum(sp["end"] - sp["start"] for sp in spans if sp["name"] == "bind")
+    assert n * delay <= post <= bind
+    assert post == pytest.approx(n * delay, rel=1.0)
+    assert bind == pytest.approx(m.bind_seconds, abs=1e-5)  # intervals are wall stamps
+
+
+def test_pipelined_bind_time_records_under_bind_post():
+    """The bind worker's time is all API time: drained inside the overlay
+    phase it still records as ``bind/post``, never as a depth-0 ``post``."""
+    delay, n = 0.002, 8
+    s = Scheduler(_slow_bind_cluster(delay, n), NativeBackend(), pipeline=True, requeue_seconds=0.0)
+    s.run_cycle()
+    assert s._bind_inflight[1].wait(timeout=30)
+    s.run_cycle()  # its overlay phase folds the finished batch
+    census = s.profile_ring.span_census()
+    assert census.get("bind/post", 0) >= 1 and "post" not in census
+    spans = s.recorder.cycles(1)[0]["spans"]
+    post = sum(sp["end"] - sp["start"] for sp in spans if sp["name"] == "bind/post")
+    assert post >= n * delay
+    s.close()

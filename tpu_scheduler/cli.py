@@ -388,10 +388,6 @@ def main(argv: list[str] | None = None) -> int:
         enable_compilation_cache()
 
     api, sched = build_scheduler(args)
-    if args.profile_dir:
-        # Link the device trace from /debug/trace's Chrome-trace JSON so the
-        # host and device timelines open side by side in Perfetto.
-        sched.recorder.device_trace_dir = args.profile_dir
 
     if args.checkpoint_dir:
         from .runtime.checkpoint import restore_scheduler

@@ -63,7 +63,15 @@ from ..models.profiles import DEFAULT_PROFILE, SchedulingProfile
 from ..ops.pack import extend_node_vocabs, pack_snapshot, repack_incremental
 from ..utils.events import SEGMENTS, FlightRecorder, waterfall
 from ..utils.metrics import CycleMetrics, MetricsRegistry, cycle_phases
-from ..utils.profiler import SLO_TIERS, ProfileRing, tier_of, tier_target, transfer_bytes_total
+from ..utils.profiler import (
+    SLO_TIERS,
+    ProfileRing,
+    gc_pauses,
+    install_gc_listener,
+    tier_of,
+    tier_target,
+    transfer_bytes_total,
+)
 from ..utils.tracing import Trace, current_trace, set_log_cycle, span
 from .fake_api import ApiError, FakeApiServer
 from .reflector import ClusterReflector
@@ -252,6 +260,14 @@ class Scheduler:
         # profiler's lifetime total is process-wide; we fold per-cycle
         # deltas so the metric is a counter, not a re-published gauge).
         self._xfer_folded = 0
+        # Collector pauses (process-wide, utils/profiler.gc_pauses): the
+        # start stamp of the last pause folded into
+        # scheduler_gc_pause_seconds; this scheduler folds only its own era.
+        install_gc_listener()
+        self._gc_folded = time.perf_counter()
+        # Seconds spent inside the API server's create_binding, lifetime;
+        # each bind phase records its share as ``bind/post``.
+        self._bind_post_s = 0.0
         self._unknown_phase_warned: set[str] = set()
         # Why-pending attribution state, reset per cycle: the snapshot
         # unschedulable pods are explained against, the remaining pod×node
@@ -706,7 +722,11 @@ class Scheduler:
             self.pre_bind_hook(namespace, name, node_name)
         pod_full = f"{namespace}/{name}"
         try:
-            self.api.create_binding(namespace, name, ObjectReference(name=node_name))
+            t_post = time.perf_counter()
+            try:
+                self.api.create_binding(namespace, name, ObjectReference(name=node_name))
+            finally:
+                self._bind_post_s += time.perf_counter() - t_post
             self.breaker.record(True)
             logger.info("Binding pod %s to %s", pod_full, node_name)
             self.metrics.inc("scheduler_bindings_total")
@@ -1327,8 +1347,11 @@ class Scheduler:
             return self.fallback_backend.schedule(packed, self.profile)
 
     def _bind_result(self, batch_snapshot: ClusterSnapshot, result, placed: list[tuple[Pod, Node]]) -> tuple[int, int]:
-        """POST a cycle result's bindings; requeue its unschedulables."""
+        """POST a cycle result's bindings; requeue its unschedulables.  The
+        time spent inside the API calls is recorded once, as ``post`` under
+        the open ``bind`` span."""
         bound = 0
+        post0 = self._bind_post_s
         node_by_name = {n.name: n for n in batch_snapshot.nodes}
         pod_by_full = {full_name(p): p for p in batch_snapshot.pending_pods()}
         for pod_full, node_name in result.bindings:
@@ -1339,6 +1362,9 @@ class Scheduler:
                 if pod_obj is not None and node_obj is not None:
                     placed.append((pod_obj, node_obj))
                     self._cycle_placed.append((pod_obj, node_obj))
+        tr = current_trace()
+        if tr is not None and result.bindings:
+            tr.record("post", self._bind_post_s - post0)
         for pod_full in result.unschedulable:
             self._mark_unschedulable(pod_full)
         return bound, len(result.unschedulable)
@@ -1423,6 +1449,9 @@ class Scheduler:
                 tr = current_trace()
                 if tr is not None:
                     tr.record("bind", err)  # the overlapped POST time, attributed at drain
+                    # All of it is API time: bind/post whatever span is open,
+                    # so no depth-0 "post" reaches the phase accounting.
+                    tr.record_path("bind/post", err)
                 continue
             if err is None:
                 self.breaker.record(True)
@@ -2548,6 +2577,14 @@ class Scheduler:
         if xfer > self._xfer_folded:
             self.metrics.inc("scheduler_device_transfer_bytes_total", xfer - self._xfer_folded)
             self._xfer_folded = xfer
+        pauses = gc_pauses()
+        i = len(pauses)
+        while i and pauses[i - 1][0] > self._gc_folded:
+            i -= 1
+        for _t, seconds, generation in pauses[i:]:
+            self.metrics.observe("scheduler_gc_pause_seconds", seconds, labels={"generation": str(generation)})
+        if i < len(pauses):
+            self._gc_folded = pauses[-1][0]
         set_log_cycle(None)
         return m
 

@@ -172,9 +172,6 @@ class FlightRecorder:
         self._timelines: OrderedDict[str, deque] = OrderedDict()  # guarded-by: _lock
         self._cycles: deque = deque(maxlen=max(1, max_cycles))  # guarded-by: _lock
         self.evicted_timelines = 0  # guarded-by: _lock — LRU overflow count; visible, never silent
-        # Set by the CLI when --profile-dir is active so chrome_trace can
-        # link the device trace next to the host spans.
-        self.device_trace_dir: str | None = None
 
     @property
     def enabled(self) -> bool:
@@ -318,11 +315,10 @@ class FlightRecorder:
         """The recorded cycles as a Chrome trace-event JSON object
         (https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU)
         — ``ph: "X"`` complete events in microseconds, one per recorded span,
-        loadable in Perfetto or chrome://tracing.  When a device trace was
-        captured (``--profile-dir``), its directory is linked in
-        ``otherData`` so the host and device timelines can be opened side by
-        side.  Tracked pods get their own process (pid 2, one thread per
-        pod — the most recently updated 64): each timeline renders as its
+        loadable in Perfetto or chrome://tracing.  (A ``--profile-dir`` trace
+        carries the same spans as ``sched:<path>`` annotations on the
+        device's clock.)  Tracked pods get their own process (pid 2, one
+        thread per pod — the most recently updated 64): each timeline renders as its
         admission-waterfall segments, so a pod's journey reads as a lane of
         named slices under the cycle spans."""
         with self._lock:
@@ -390,11 +386,8 @@ class FlightRecorder:
                             "args": {"pod": pf, "kind": tl[i].get("kind")},
                         }
                     )
-        trace = {
+        return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
             "otherData": {"recorded_cycles": len(recs)},
         }
-        if self.device_trace_dir:
-            trace["otherData"]["device_trace_dir"] = self.device_trace_dir
-        return trace

@@ -49,6 +49,10 @@ DIRTY_BUCKETS = (1.0, 4.0, 16.0, 64.0, 256.0, 1024.0, 4096.0, 16384.0)
 # same-cycle segments through multi-minute backoff/backlog residency — the
 # low end needs sub-cadence resolution (one cycle interval ~ 1 s).
 TTB_SEGMENT_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0, 600.0)
+# Collector pauses (utils/profiler.gc_pauses): young-generation collections
+# of tens of microseconds through full collections over a heap of millions of
+# objects.
+GC_PAUSE_BUCKETS = (0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 
 # Histogram name -> bucket bounds; the one registration point the README
 # drift gate (scripts/lint.py) and to_prometheus share.
@@ -62,6 +66,7 @@ HISTOGRAM_BUCKETS = {
     "scheduler_pending_age_seconds": PENDING_AGE_BUCKETS,
     "scheduler_delta_dirty_pods": DIRTY_BUCKETS,
     "scheduler_ttb_segment_seconds": TTB_SEGMENT_BUCKETS,
+    "scheduler_gc_pause_seconds": GC_PAUSE_BUCKETS,
 }
 
 

@@ -2,12 +2,12 @@
 ROADMAP perf items ("profile the cycle" is where both the constrained-scale
 and the incremental-cycle work start).
 
-Four pieces:
+Five pieces:
 
   • **Attribution trees** — ``build_tree`` folds one cycle's hierarchical
     ``Trace`` (utils/tracing.py path-keyed spans) into a nested node tree
     with per-node total and SELF time (total minus children — the disjoint
-    quantity that sums to the attributed wall).  ``coverage`` is
+    quantity that sums to the attributed wall); its ``coverage`` is
     1 − other/wall: the share of the cycle wall the tree explains.  The
     closed span vocabulary is ``SPAN_CATALOGUE`` (drift-gated against the
     README "Profiling" catalogue by the PROF analyze rule).
@@ -25,6 +25,11 @@ Four pieces:
     host→device bytes at the TpuBackend's device_put seam.  Together with
     the epoch driver's ``dispatch``/``host-sync`` spans, "solve time"
     decomposes into compile / device-execute / host-sync / Python.
+  • **Collector pauses** — ``install_gc_listener`` times every garbage
+    collection (``gc.callbacks``) into a bounded record read through
+    ``gc_pauses``; the controller folds it into
+    ``scheduler_gc_pause_seconds{generation=}``, and while a profiler
+    session records each collection is a ``sched:gc`` annotation.
 
 SLO burn: ``tier_of`` maps pod priority to a closed tier set with per-tier
 time-to-bind targets (``SLO_TIERS``); the controller's pending-age tracker
@@ -40,23 +45,26 @@ the scorecard ``profile`` block carries exclusively the deterministic parts
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
+from collections import deque
 
-from .tracing import Trace, current_trace
+from .tracing import Trace, current_trace, profiler_annotation
 
 __all__ = [
     "SPAN_CATALOGUE",
     "SLO_TIERS",
     "tier_of",
     "build_tree",
-    "coverage",
     "ProfileRing",
     "ReplicaProfileRegistry",
     "ReplicaLatencyRegistry",
     "install_jax_profile_hooks",
     "record_transfer",
     "transfer_bytes_total",
+    "install_gc_listener",
+    "gc_pauses",
     "span_cost_estimate",
 ]
 
@@ -95,6 +103,7 @@ SPAN_CATALOGUE = (
     "pa",          # filter sub-span: positive-affinity bootstrap min-rank
     "spread",      # filter sub-span: spread rank-prefix admission + cascade
     "commit",      # choose sub-span: domain-state commit of accepted claims
+    "post",        # bind sub-span: the API server's binding calls, summed per bind phase
     "snapshot",    # rebalance sub-span: consistent packing-view build
     "plan",        # rebalance sub-span: batch selection / autoscale sub-span: catalog what-if
     "migrate",     # rebalance sub-span: breaker-gated unbinds + cordons
@@ -167,13 +176,6 @@ def build_tree(trace: Trace, wall: float) -> dict:
         "coverage": (attributed / wall) if wall > 0 else 1.0,
         "children": root["children"],
     }
-
-
-def coverage(trace: Trace, wall: float) -> float:
-    """1 − other/wall for one cycle (attributed = depth-0 span total)."""
-    if wall <= 0:
-        return 1.0
-    return min(1.0, sum(trace.top_level().values()) / wall)
 
 
 def _quantile(sorted_vals: list[float], q: float) -> float:
@@ -291,12 +293,6 @@ class ProfileRing:
         part the sim scorecard may carry."""
         with self._lock:
             return {p: e["count"] for p, e in sorted(self._paths.items())}
-
-    def aggregate_coverage(self) -> float:
-        with self._lock:
-            if self._wall_total <= 0:
-                return 1.0
-            return 1.0 - self._other_total / self._wall_total
 
     def overhead_estimate(self) -> dict:
         """Measured profiler overhead over the run: (lifetime span events ×
@@ -513,3 +509,47 @@ def install_jax_profile_hooks() -> bool:
         return True
     except Exception:  # noqa: BLE001 — jax absent/old: profiling degrades, never crashes
         return False
+
+
+# -- collector pauses ---------------------------------------------------------
+
+GC_PAUSE_CAPACITY = 8192
+# (perf_counter start, seconds, generation) of the latest collections.  The
+# callback takes no lock: a collection can fire in any thread at any
+# allocation, including one made while that thread holds a lock the callback
+# would want (MetricsRegistry._lock).  A collection runs start to stop under
+# the interpreter lock, so one open slot suffices, and ``list()`` of the deque
+# copies it in one step no callback can interleave.
+_gc_pauses: deque = deque(maxlen=GC_PAUSE_CAPACITY)
+_gc_open: list = [None, None]  # start stamp, open sched:gc annotation
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    if phase == "start":
+        cls = profiler_annotation()
+        ann = None
+        if cls is not None:
+            ann = cls("sched:gc", generation=info["generation"])
+            ann.__enter__()
+        _gc_open[1] = ann
+        _gc_open[0] = time.perf_counter()
+        return
+    t0, ann = _gc_open
+    if t0 is None:
+        return  # installed mid-collection
+    _gc_pauses.append((t0, time.perf_counter() - t0, info["generation"]))
+    _gc_open[0] = _gc_open[1] = None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+def install_gc_listener() -> None:
+    """Time every garbage collection of the process (idempotent)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_pauses() -> list[tuple[float, float, int]]:
+    """The latest collections, oldest first: ``(perf_counter start,
+    seconds, generation)``, at most ``GC_PAUSE_CAPACITY``."""
+    return list(_gc_pauses)

@@ -16,8 +16,14 @@ solves, the pipelined bind worker) sees no active trace and its spans
 degrade to two clock reads — never a concurrent mutation of the main
 thread's tree (the THRD stance: no shared mutable state, no lock needed).
 
-``device_profile(dir)`` wraps ``jax.profiler`` for TPU traces of the scoring
-step; it is a no-op if profiling can't start.  ``configure_logging`` grows a
+While a ``jax.profiler`` session records, every span also opens a
+``jax.profiler.TraceAnnotation`` named ``sched:<path>`` (``sched:bind``,
+``sched:delta/index``), so the cycle's phases land in the profiler's own
+trace beside the device ops, on its clock.  With no session a span pays one
+``TraceAnnotation.is_enabled()`` call; ``Trace.record`` entries (sums, not
+intervals) never reach the profiler.  ``device_profile(dir)`` wraps
+``jax.profiler`` for traces of whole runs; it is a no-op if profiling can't
+start.  ``configure_logging`` grows a
 ``--log-format json`` path: one JSON object per line (ts, level, logger,
 msg, cycle) so the daemon's logs are machine-parseable; ``set_log_cycle``
 tags every line emitted during a cycle with its number.
@@ -28,6 +34,7 @@ from __future__ import annotations
 import contextlib
 import json
 import logging
+import sys
 import threading
 import time
 
@@ -37,6 +44,7 @@ __all__ = [
     "span",
     "Trace",
     "current_trace",
+    "profiler_annotation",
     "base_name",
     "device_profile",
     "configure_logging",
@@ -55,6 +63,25 @@ def _stack() -> list:
     if s is None:
         s = _tls.stack = []
     return s
+
+
+# jax.profiler.TraceAnnotation, resolved once on first use after jax.profiler
+# is loaded: no profiler session can exist before that, and this module never
+# imports jax itself.
+_annotation_cls = None
+
+
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None.  Imports nothing, so a garbage-collector callback may call it."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        cls = _annotation_cls = mod.TraceAnnotation
+    return cls if cls.is_enabled() else None
 
 
 def base_name(segment: str) -> str:
@@ -149,10 +176,10 @@ class Trace:
         interval is synthesized as ending now — the overlapped-bind drain
         knows only its duration, and an approximate box in the trace beats
         an invisible one."""
-        path = f"{self._path}/{name}" if self._path else name
-        self._record_path(path, seconds, perf_start)
+        self.record_path(f"{self._path}/{name}" if self._path else name, seconds, perf_start)
 
-    def _record_path(self, path: str, seconds: float, perf_start: float | None) -> None:
+    def record_path(self, path: str, seconds: float, perf_start: float | None = None) -> None:
+        """Record under an explicit full ``path``, whatever span is open."""
         self.durations[path] = self.durations.get(path, 0.0) + seconds
         self.counts[path] = self.counts.get(path, 0) + 1
         end = time.perf_counter() if perf_start is None else perf_start + seconds
@@ -166,10 +193,6 @@ class Trace:
         s = _stack()
         if self in s:
             s.remove(self)
-
-    def summary(self) -> dict[str, float]:
-        """Path -> accumulated seconds (depth-0 paths are plain names)."""
-        return dict(self.durations)
 
     def top_level(self) -> dict[str, float]:
         """Depth-0 durations only — the disjoint cycle phases whose sum is
@@ -186,9 +209,11 @@ class span:
     """Time a block into the active trace (hierarchically).  A plain class
     context manager, not @contextmanager: this sits on the per-round hot
     path and the generator protocol costs ~2 µs per use that a __slots__
-    class does not (the <2 % profiler-overhead budget is built from this)."""
+    class does not (the <2 % profiler-overhead budget is built from this).
+    Inside an active trace it also opens the ``sched:<path>`` profiler
+    annotation while a profiler session records."""
 
-    __slots__ = ("name", "_t0", "_tr", "_prev")
+    __slots__ = ("name", "_t0", "_tr", "_prev", "_ann")
 
     def __init__(self, name: str):
         self.name = name
@@ -196,10 +221,18 @@ class span:
     def __enter__(self) -> "span":
         s = getattr(_tls, "stack", None)
         tr = self._tr = s[-1] if s else None
+        self._ann = None
         if tr is not None:
             prev = tr._path
             self._prev = prev
-            tr._path = f"{prev}/{self.name}" if prev else self.name
+            path = tr._path = f"{prev}/{self.name}" if prev else self.name
+            # Inlined fast path: with the class resolved and no session, one
+            # is_enabled() call and no function call of ours.
+            if _annotation_cls is None or _annotation_cls.is_enabled():
+                cls = profiler_annotation()
+                if cls is not None:
+                    self._ann = cls(f"sched:{path}")
+                    self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -209,7 +242,9 @@ class span:
         if tr is not None:
             path = tr._path
             tr._path = self._prev
-            tr._record_path(path, dt, self._t0)
+            tr.record_path(path, dt, self._t0)
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
 
 
 @contextlib.contextmanager
