@@ -11,6 +11,7 @@ import os
 import pytest
 
 from tpu_scheduler.backends.native import NativeBackend
+from tpu_scheduler.delta import DeltaEngine
 from tpu_scheduler.runtime.controller import Scheduler
 from tpu_scheduler.runtime.fake_api import FakeApiServer
 from tpu_scheduler.testing import make_node, make_pod, synth_cluster
@@ -306,7 +307,9 @@ def test_epoch_refresh_escalates_periodically():
     assert sched.delta.stats()["full_solve_reasons"].get("epoch-refresh", 0) >= 2
 
 
-def test_closure_overflow_escalates():
+def test_closure_overflow_escalates(monkeypatch):
+    from tpu_scheduler.delta.index import DeltaIndex
+
     api = FakeApiServer()
     api.create_node(make_node("n1", cpu="64", memory="128Gi"))
     sched = _sched(api)
@@ -316,9 +319,197 @@ def test_closure_overflow_escalates():
     sched.run_cycle()
     for i in range(8):  # dirty wave > max(2, half the cluster's pods)
         api.create_pod(make_pod(f"wave-{i}", cpu="100m", memory="64Mi"))
+    # The overflow is certain before the fold (8 pending, no standing
+    # verdicts): the plan escalates without folding and drops the buffer.
+    plans = []
+    plan = sched.delta.plan
+
+    def watched_plan(*a, **k):
+        buffered = sched.delta.index.pending_events()
+        out = plan(*a, **k)
+        plans.append((out, buffered, sched.delta.index.pending_events()))
+        return out
+
+    def no_fold(self, state, events):
+        raise AssertionError("the fold ran on a certain overflow")
+
+    monkeypatch.setattr(sched.delta, "plan", watched_plan)
+    monkeypatch.setattr(DeltaIndex, "fold", no_fold)
     m = sched.run_cycle()
     assert m.bound == 8
     assert "closure-overflow" in sched.delta.stats()["full_solve_reasons"]
+    assert sched.delta.stats()["folds_skipped"] == 1
+    assert sched.metrics.snapshot()["scheduler_delta_fold_skipped_total"] == 1
+    assert len(plans) == 1 and plans[0][0] is None and plans[0][1] > 0 and plans[0][2] == 0
+    monkeypatch.undo()
+
+    # Counter-case: standing verdicts keep pending − standing under the
+    # threshold, so the fold runs; freed capacity retires the verdicts and
+    # the post-close test decides the overflow.
+    folds = []
+    fold = DeltaIndex.fold
+
+    def counted_fold(self, state, events):
+        folds.append(len(events))
+        return fold(self, state, events)
+
+    monkeypatch.setattr(DeltaIndex, "fold", counted_fold)
+    api = FakeApiServer()
+    api.create_node(make_node("n1", cpu="4", memory="8Gi"))
+    api.create_pod(make_pod("filler", cpu="3", memory="1Gi"))
+    sched = _sched(api)
+    sched.delta.OVERFLOW_MIN = 2
+    sched.run_cycle()
+    for i in range(8):
+        api.create_pod(make_pod(f"big-{i}", cpu="2", memory="1Gi"))
+    sched.run_cycle()  # 8 unschedulable verdicts from a (certain) overflow
+    assert sched.delta.stats()["standing_verdicts"] == 8
+    assert sched.delta.stats()["folds_skipped"] == 1
+    sched.run_cycle()  # nothing changed: a delta cycle skips all eight
+    api.delete_pod("default", "filler")
+    folds.clear()
+    m = sched.run_cycle()
+    assert folds == [1]  # the delete folded, and retired all eight verdicts
+    assert sched.delta.stats()["folds_skipped"] == 1
+    assert sched.delta.stats()["full_solve_reasons"] == {"closure-overflow": 2, "cold": 1}
+    assert m.bound == 2
+
+
+def test_cycle_aborted_after_a_skipped_fold_leaves_the_engine_cold(monkeypatch):
+    """A cycle that raises between a pre-fold overflow escalation and its
+    commit must not leave the engine holding placements the dropped watch
+    events changed: the next small cycle rebuilds instead of solving
+    against a ledger that still counts the deleted wave."""
+    api = FakeApiServer()
+    api.create_node(make_node("n1", cpu="4", memory="8Gi"))
+    for i in range(8):
+        api.create_pod(make_pod(f"w1-{i}", cpu="500m", memory="64Mi"))
+    sched = _sched(api)
+    sched.delta.OVERFLOW_MIN = 2
+    assert sched.run_cycle().bound == 8  # cold: the node is full
+    for i in range(8):
+        api.delete_pod("default", f"w1-{i}")
+        api.create_pod(make_pod(f"w2-{i}", cpu="100m", memory="64Mi"))
+
+    def boom(*a, **k):
+        raise RuntimeError("cycle ends before its commit")
+
+    monkeypatch.setattr(sched, "_run_batch_cycle", boom)
+    with pytest.raises(RuntimeError):
+        sched.run_cycle()
+    monkeypatch.undo()
+    stats = sched.delta.stats()
+    assert stats["folds_skipped"] == 1 and not stats["valid"]
+    assert sched.delta.index.pending_events() == 0
+    for i in range(8):
+        api.delete_pod("default", f"w2-{i}")
+    api.create_pod(make_pod("solo", cpu="4", memory="64Mi"))
+    m = sched.run_cycle()  # one pending pod: no overflow, but no state either
+    assert m.bound == 1
+    assert sched.delta.stats()["full_solve_reasons"] == {"cold": 2}
+    assert set(sched.delta.state.placements) == {"default/solo"}
+    _audit_capacity(sched)
+
+
+class _FoldFirstEngine(DeltaEngine):
+    """The engine whose plan always folds before the overflow test (the
+    path without the pre-check)."""
+
+    @staticmethod
+    def _dirty_floor(st, pending, preempting):
+        return 0
+
+
+def _wave_script(api, clock, sched):
+    """Waves created, cycled, deleted and created again, with requeued
+    pods (failed binds) and standing verdicts (an unmatched selector) in
+    between; yields after every cycle."""
+
+    def cycle():
+        clock.t += 1.0
+        sched.run_cycle()
+        return True
+
+    def wave(tag, n):
+        for i in range(n):
+            api.create_pod(make_pod(f"{tag}-{i}", cpu="250m", memory="256Mi", labels={"wave": tag}))
+
+    def retire(tag):
+        for p in api.list_pods():
+            if (p.metadata.labels or {}).get("wave") == tag:
+                api.delete_pod(p.metadata.namespace or "default", p.metadata.name)
+
+    for i in range(5):
+        api.create_pod(make_pod(f"stuck-{i}", cpu="100m", memory="64Mi", node_selector={"zone": "nowhere"}))
+    yield cycle()  # cold
+    wave("w1", 24)
+    yield cycle()
+    retire("w1")
+    wave("w2", 24)
+    api.fail_next_bindings = 4
+    yield cycle()
+    yield cycle()  # the four failed binds retry
+    api.create_pod(make_pod("late", cpu="250m", memory="256Mi"))
+    yield cycle()  # a small delta cycle
+    api.create_pod(make_pod("stuck-5", cpu="100m", memory="64Mi", node_selector={"zone": "nowhere"}))
+    yield cycle()
+    retire("w2")
+    wave("w3", 30)
+    yield cycle()
+    retire("w3")
+    yield cycle()
+    wave("w4", 6)
+    yield cycle()
+
+
+def _engine_view(sched) -> dict:
+    st = sched.delta.state
+    return {
+        "placements": {pf: (r, n, req.tolist()) for pf, (r, n, req) in st.placements.items()},
+        "used64": st.used64.tolist(),
+        "unsched": dict(st.unsched),
+        "reasons": sched.delta.stats()["full_solve_reasons"],
+        "bindings": sorted((p.metadata.name, p.spec.node_name) for p in sched.api.list_pods() if p.spec.node_name),
+    }
+
+
+@pytest.mark.parametrize("preempting", [False, True])
+def test_overflow_precheck_matches_the_fold_first_path(preempting, monkeypatch):
+    """The pre-fold overflow escalation does the same work: after every
+    cycle of a scripted wave sequence, the SolveState, the escalation
+    tally and the bindings equal those of the engine that folds first."""
+    import random
+
+    import tpu_scheduler.delta as delta_pkg
+    from tpu_scheduler.models.profiles import DEFAULT_PROFILE
+
+    def build(engine_cls):
+        monkeypatch.setattr(delta_pkg, "DeltaEngine", engine_cls)
+        api = FakeApiServer()
+        for i in range(4):
+            api.create_node(make_node(f"n{i}", cpu="8", memory="16Gi", labels={"zone": "a" if i % 2 else "b"}))
+        clock = FakeClock()
+        sched = Scheduler(
+            api,
+            NativeBackend(),
+            profile=DEFAULT_PROFILE.with_(preemption=preempting),
+            clock=clock,
+            requeue_seconds=0.0,
+            rng=random.Random(7),
+        )
+        sched.delta.OVERFLOW_MIN = 4
+        return _wave_script(api, clock, sched), sched
+
+    new_steps, new = build(DeltaEngine)
+    old_steps, old = build(_FoldFirstEngine)
+    assert type(old.delta) is _FoldFirstEngine and type(new.delta) is DeltaEngine
+    for n_cycle, _ in enumerate(zip(new_steps, old_steps)):
+        assert _engine_view(new) == _engine_view(old), f"diverged after cycle {n_cycle}"
+    # No verdict retires in this script, so the pre-check takes every
+    # overflow; the fold-first engine never skips a fold.
+    assert new.delta.folds_skipped == new.delta.full_solve_reasons["closure-overflow"] >= 3
+    assert old.delta.folds_skipped == 0
+    assert new.delta.delta_cycles >= 2
 
 
 def test_preempting_profile_keeps_eligible_pods_dirty():

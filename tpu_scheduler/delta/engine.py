@@ -18,6 +18,8 @@ rebuild.  Escalation happens only on the closed ``ESCALATION_REASONS``:
                     lacks (full pack re-derives scales)
   closure-overflow  the invalidation closure grew past the threshold —
                     a full sweep is cheaper than incremental bookkeeping
+                    (tested first on a lower bound of the dirty set, before
+                    the watch events fold, and again after the closure)
   epoch-refresh     periodic paranoia full-wave (bounds the lifetime of any
                     undetected bookkeeping drift)
   mesh-rebind       a fleet takeover/resize rebound absorbed shards onto
@@ -104,6 +106,7 @@ class DeltaEngine:
         self.delta_cycles = 0
         self.full_solve_reasons: dict[str, int] = {}
         self.skipped_total = 0
+        self.folds_skipped = 0
         self.dirty_sizes: list[int] = []
         self.shadow_checks = 0
         self.shadow_mismatches = 0
@@ -140,6 +143,18 @@ class DeltaEngine:
 
     # -- plan ---------------------------------------------------------------
 
+    def _overflow_limit(self, snapshot) -> int:
+        """The dirty-set size above which the cycle escalates as
+        ``closure-overflow`` (both the pre-fold and the post-close test)."""
+        return max(self.OVERFLOW_MIN, int(self.OVERFLOW_FRAC * len(snapshot.pods)))
+
+    @staticmethod
+    def _dirty_floor(st: SolveState, pending: list, preempting: bool) -> int:
+        """A lower bound on the cycle's dirty set, known before the fold:
+        the fold and the closure only remove standing verdicts, so at most
+        ``len(st.unsched)`` pending pods can still be skipped."""
+        return len(pending) if preempting else len(pending) - len(st.unsched)
+
     def _escalate(self, reason: str):
         self._full_reason = reason
         self._invalid_reason = None
@@ -157,7 +172,18 @@ class DeltaEngine:
         dirty): the preemption pass retries exactly the pods the cycle
         marked unschedulable, and a PDB-blocked preemption must re-attempt
         as budgets thaw — a standing verdict would silently starve it.  The
-        carried-capacity fast path still applies."""
+        carried-capacity fast path still applies.
+
+        A closure overflow that ``_dirty_floor`` already makes certain
+        escalates BEFORE the fold: the buffered events are dropped unfolded
+        (the rebuild at commit reads the snapshot, which reflects them) and
+        the fold counts as skipped.  The state goes with them, so a cycle
+        that ends before its commit (an exception, a lost lease) leaves the
+        engine cold, never holding placements the dropped events changed.
+        The one visible difference from folding first: a cycle whose fold
+        would also have met a vocabulary miss counts under
+        ``closure-overflow`` instead of ``vocab-change`` (the reason feeds
+        only the escalation counter; the same full wave runs)."""
         self._full_reason = None
         st = self.state
         if st is None:
@@ -174,6 +200,14 @@ class DeltaEngine:
             return self._escalate("vocab-change")
         if st.delta_cycles_since_full >= self.epoch_refresh:
             return self._escalate("epoch-refresh")
+        limit = self._overflow_limit(snapshot)
+        if self._dirty_floor(st, pending, preempting) > limit:
+            self.index.take()
+            self.state = None
+            self.folds_skipped += 1
+            if self.metrics is not None:
+                self.metrics.inc("scheduler_delta_fold_skipped_total")
+            return self._escalate("closure-overflow")
         with span("index"):
             fold = self.index.fold(st, self.index.take())
         if not fold.ok:
@@ -188,7 +222,7 @@ class DeltaEngine:
             else:
                 dirty = [p for p in pending if full_name(p) not in standing]
                 skipped = len(pending) - len(dirty)
-        if len(dirty) > max(self.OVERFLOW_MIN, int(self.OVERFLOW_FRAC * len(snapshot.pods))):
+        if len(dirty) > limit:
             return self._escalate("closure-overflow")
         alloc_used = None
         if dirty:
@@ -333,6 +367,7 @@ class DeltaEngine:
             "full_solves": sum(self.full_solve_reasons.values()),
             "full_solve_reasons": dict(sorted(self.full_solve_reasons.items())),
             "skipped_total": self.skipped_total,
+            "folds_skipped": self.folds_skipped,
             "standing_verdicts": len(self.state.unsched) if self.state is not None else 0,
             "dirty_sizes": sizes,
             "shadow_checks": self.shadow_checks,
