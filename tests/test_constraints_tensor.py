@@ -369,6 +369,63 @@ def test_stalled_constraint_auction_stops_early():
     assert placed >= len(rn.bindings) + 200  # pre-bound + at least the one-shot count
 
 
+def test_deferred_claimant_claims_after_the_pods_behind_it(monkeypatch):
+    """A claimant the filter defers holds no capacity, so it must not block
+    the lower-ranked claimants of its node: from the round after its
+    deferral it claims after them.  Here the filter defers the top-ranked
+    pod every round; the node fits it alone or the two pods behind it, and
+    those two must bind (before the rule, nothing bound and the stall stop
+    requeued all three)."""
+    import tpu_scheduler.ops.constraints as C
+
+    nodes = [make_node("n0", cpu=2, memory="8Gi", labels={"zone": "a"})]
+    pods = [
+        make_pod("head", cpu="2", memory="1Gi", priority=10, labels={"app": "h"},
+                 anti_affinity=[PodAntiAffinityTerm(match_labels={"app": "h"}, topology_key="zone")]),
+        make_pod("p", cpu="1", memory="1Gi", labels={"app": "p"}),
+        make_pod("q", cpu="1", memory="1Gi", labels={"app": "q"}),
+    ]
+    snap = ClusterSnapshot.build(nodes, pods)
+    packed = _packed_with_constraints(snap)
+    assert packed.constraints is not None
+    real_filter = C.constraint_filter
+
+    def defer_head(xp, accepted, choice, ranks, *a, **k):
+        return real_filter(xp, accepted, choice, ranks, *a, **k) & (ranks != 0)
+
+    monkeypatch.setattr(C, "constraint_filter", defer_head)
+    r = NativeBackend().schedule(packed, DEFAULT_PROFILE)
+    assert sorted(b[0] for b in r.bindings) == ["default/p", "default/q"]
+    assert r.unschedulable == ["default/head"]
+
+
+@pytest.mark.parametrize("backend", [NativeBackend, TpuBackend], ids=["native", "tpu"])
+def test_constraint_widths_keep_the_widest_padding_seen(backend):
+    """A shared ``widths`` record pads each constraint axis to the widest it
+    has been: a cycle with 3 distinct spread constraints after one with 9
+    keeps the 16-wide spread axis (8 without the record), and is scheduled
+    exactly as without it."""
+    nodes = [make_node(f"n{i}", cpu=8, memory="32Gi", labels={"zone": "abc"[i % 3]}) for i in range(6)]
+
+    def pending(n_apps):
+        return [
+            make_pod(f"p{a}-{k}", cpu="1", memory="1Gi", labels={"app": f"a{a}"},
+                     topology_spread=[TopologySpreadConstraint(topology_key="zone", max_skew=1, match_labels={"app": f"a{a}"})])
+            for a in range(n_apps) for k in range(2)
+        ]
+
+    widths: dict = {}
+    wide = _packed_with_constraints(ClusterSnapshot.build(nodes, pending(9)), widths=widths)
+    assert wide.constraints.pod_sp_declares.shape[1] == 16 and widths["s"] == 16
+    snap = ClusterSnapshot.build(nodes, pending(3))
+    plain = _packed_with_constraints(snap)
+    sticky = _packed_with_constraints(snap, widths=widths)
+    assert plain.constraints.pod_sp_declares.shape[1] == 8
+    assert sticky.constraints.pod_sp_declares.shape[1] == 16 and widths["s"] == 16
+    a, b = backend().schedule(plain, DEFAULT_PROFILE), backend().schedule(sticky, DEFAULT_PROFILE)
+    assert sorted(a.bindings) == sorted(b.bindings) and len(a.bindings) == 6
+
+
 def test_dense_and_fallback_filter_paths_agree(monkeypatch):
     """The DENSE_CELLS fast path (exclusive-cumsum predecessor checks +
     row scatters) and the sort/scatter fallback in constraint_filter /

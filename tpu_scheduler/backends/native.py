@@ -95,6 +95,7 @@ class NativeBackend(SchedulingBackend):
         ranks = np.arange(p, dtype=np.uint32)  # already in priority-rank order
         rounds = 0
         stall = 0  # consecutive zero-acceptance rounds (ops/assign.py STALL_ROUNDS)
+        deferred = np.zeros((p,), dtype=bool)  # deferred by the filter in an earlier round: claims last
 
         while rounds < profile.max_rounds and active.any() and stall < STALL_ROUNDS:
             # Per-round attribution (utils/profiler.py): each round nests a
@@ -150,7 +151,7 @@ class NativeBackend(SchedulingBackend):
                     ch = np.where(cand, choice, n).astype(np.int32)
                     claim = np.where(cand[:, None], req, 0)
 
-                    order = np.argsort(ch, kind="stable")
+                    order = np.argsort(ch * 2 + deferred if cons is not None else ch, kind="stable")
                     ch_s = ch[order]
                     claim_s = claim[order].astype(np.int64)
                     cum = claim_s.cumsum(axis=0)
@@ -173,10 +174,12 @@ class NativeBackend(SchedulingBackend):
                         # fusion; ``spans=span`` opens the filter/aa|pa|
                         # spread sub-spans so the attribution names WHICH
                         # constraint family dominates, not just the filter.
+                        prefix = accepted
                         with span("filter"):
                             accepted = constraint_filter(
                                 np, accepted, choice, ranks, cpods, cstate, cmeta, hard_pa=hard_pa, spans=span
                             )
+                        deferred |= prefix & ~accepted
                         stall = 0 if accepted.any() else stall + 1
                         with span("commit"):
                             cstate = constraint_commit(

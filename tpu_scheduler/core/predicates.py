@@ -252,14 +252,11 @@ def make_affinity_checker(
     keys: set[str] = set()
 
     # Direction A: domains holding a pod matched by one of my terms.
-    if my_terms:
-        for q, qnode in chain(snapshot.placed_pods(), extra_placed):
-            if q.metadata.namespace != my_ns:
-                continue
-            for t in my_terms:
-                if term_matches(t, q.metadata.labels):
-                    blocked.add(node_topology_domain(qnode, t.topology_key))
-                    keys.add(t.topology_key)
+    for t in my_terms:
+        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
+            if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
+                blocked.add(node_topology_domain(qnode, t.topology_key))
+                keys.add(t.topology_key)
     # Direction B: domains of placed term-carriers whose term matches me.
     carriers = chain(
         snapshot.placed_pods_with_terms(),
@@ -322,7 +319,7 @@ def make_pod_affinity_checker(
     term_domains: list[set[tuple[str, str]] | None] = []
     for t in my_terms:
         doms: set[tuple[str, str]] = set()
-        for q, qnode in chain(snapshot.placed_pods(), extra_placed):
+        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
             if exclude and full_name(q) in exclude:
                 continue
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
@@ -380,7 +377,7 @@ def make_spread_checker(
             v = (n.metadata.labels or {}).get(c.topology_key)
             if v is not None:
                 counts.setdefault(v, 0)
-        for q, qnode in chain(snapshot.placed_pods(), extra_placed):
+        for q, qnode in chain(snapshot.placed_matching(my_ns, c.match_labels), extra_placed):
             if exclude and full_name(q) in exclude:
                 continue
             v = (qnode.metadata.labels or {}).get(c.topology_key)
@@ -471,7 +468,7 @@ def make_preferred_pod_affinity_scorer(
     per_term: list[tuple[float, str, dict[tuple[str, str], int]]] = []
     for w, t in weighted:
         counts: dict[tuple[str, str], int] = {}
-        for q, qnode in chain(snapshot.placed_pods(), extra_placed):
+        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
                 d = node_topology_domain(qnode, t.topology_key)
                 counts[d] = counts.get(d, 0) + 1
@@ -501,7 +498,7 @@ def make_soft_spread_scorer(
     per_constraint: list[tuple[str, dict[str, int]]] = []
     for c in constraints:
         counts: dict[str, int] = {}
-        for q, qnode in chain(snapshot.placed_pods(), extra_placed):
+        for q, qnode in chain(snapshot.placed_matching(my_ns, c.match_labels), extra_placed):
             v = (qnode.metadata.labels or {}).get(c.topology_key)
             if v is None or q.metadata.namespace != my_ns:
                 continue
@@ -542,6 +539,14 @@ PREDICATE_CHAIN: list[tuple[InvalidNodeReason, Callable[[Pod, Node, ClusterSnaps
     (InvalidNodeReason.TOPOLOGY_SPREAD_VIOLATION, topology_spread_ok),
 ]
 
+# The one-shot inter-pod predicates of the chain, each with the factory of
+# the per-pod checker it builds on every call.
+_CHECKER_OF: dict[Callable, Callable[[Pod, ClusterSnapshot], Callable[[Node], bool]]] = {
+    anti_affinity_ok: make_affinity_checker,
+    pod_affinity_ok: make_pod_affinity_checker,
+    topology_spread_ok: make_spread_checker,
+}
+
 
 # shape: (pod: obj, node: obj, snapshot: obj) -> obj
 def check_node_validity(pod: Pod, node: Node, snapshot: ClusterSnapshot) -> InvalidNodeReason | None:
@@ -563,15 +568,30 @@ def unschedulable_reason_counts(pod: Pod, snapshot: ClusterSnapshot) -> tuple[di
     ``(counts-by-reason-value, feasible_nodes, nodes_total)`` — the payload
     of the flight recorder's "unschedulable" event and the /debug why-pending
     route (utils/events.py, runtime/http_api.py).  O(nodes) host work per
-    pod: callers on the cycle path budget it (Scheduler.EXPLAIN_WORK)."""
+    pod: callers on the cycle path budget it (Scheduler.EXPLAIN_WORK).
+
+    The same chain as :func:`check_node_validity`, node for node, except
+    that each inter-pod predicate's checker (anti-affinity, pod affinity,
+    hard spread) is built once for the pod, when the first node reaches it,
+    instead of once a node: a build walks every placed pod."""
+    chain_ = [(reason.value, pred, _CHECKER_OF.get(pred)) for reason, pred in PREDICATE_CHAIN]
+    checkers: dict[Callable, Callable[[Node], bool]] = {}
     counts: dict[str, int] = {}
     feasible = 0
     for node in snapshot.nodes:
-        reason = check_node_validity(pod, node, snapshot)
-        if reason is None:
-            feasible += 1
+        for reason, pred, make in chain_:
+            if make is None:
+                ok = pred(pod, node, snapshot)
+            else:
+                check = checkers.get(pred)
+                if check is None:
+                    check = checkers[pred] = make(pod, snapshot)
+                ok = check(node)
+            if not ok:
+                counts[reason] = counts.get(reason, 0) + 1
+                break
         else:
-            counts[reason.value] = counts.get(reason.value, 0) + 1
+            feasible += 1
     return counts, feasible, len(snapshot.nodes)
 
 

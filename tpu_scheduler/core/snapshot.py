@@ -78,6 +78,9 @@ class ClusterSnapshot:
     # anti-affinity terms (the direction-B forbidders).
     _placed: list = field(default_factory=list, compare=False, repr=False)
     _placed_with_terms: list = field(default_factory=list, compare=False, repr=False)
+    # Lazy index of ``_placed`` by (namespace, label key, label value), for
+    # the inter-pod checkers' selector walks (placed_matching).
+    _placed_by_label: dict | None = field(default=None, compare=False, repr=False)
     # Lazy pending-pod memo (immutable snapshot, so one scan suffices): the
     # controller consults the pending list several times per cycle — at
     # flagship scale each uncached scan walks 200k+ pods.
@@ -117,6 +120,23 @@ class ClusterSnapshot:
     def placed_pods_with_terms(self) -> list:
         """Placements whose pod declares anti-affinity terms."""
         return self._placed_with_terms
+
+    def placed_matching(self, namespace: str | None, match_labels: dict[str, str] | None) -> list:
+        """The placements in ``namespace`` whose pod carries the rarest pair
+        of ``match_labels`` (all placements when it has none): a superset of
+        the placements a selector with those labels matches, from an index
+        built once a snapshot, so a per-pod checker walks the pods its
+        selector can match and not every placed pod."""
+        if not match_labels:
+            return self._placed
+        index = self._placed_by_label
+        if index is None:
+            index = {}
+            for q, qnode in self._placed:
+                for k, v in (q.metadata.labels or {}).items():
+                    index.setdefault((q.metadata.namespace, k, v), []).append((q, qnode))
+            object.__setattr__(self, "_placed_by_label", index)
+        return min((index.get((namespace, k, v), ()) for k, v in match_labels.items()), key=len)
 
     def pods_on_node(self, node_name: str) -> list[Pod]:
         """Snapshot equivalent of the reference's live field-selector list

@@ -395,8 +395,13 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
         claim = jnp.where(cand[:, None], ps["pod_req"], 0)
 
         # Group claimants per node; the stable sort preserves the compacted
-        # (= priority) order among each node's claimants.
-        order = jnp.argsort(ch, stable=True)
+        # (= priority) order among each node's claimants.  On constrained
+        # cycles a pod the filter has deferred claims after the others: a
+        # deferred claimant holds no capacity, yet at the head of a node's
+        # prefix it would block every claimant behind it, round after round,
+        # until the stall stop stranded them all.
+        key = ch * 2 + ps["deferred"].astype(jnp.int32) if cmeta is not None else ch
+        order = jnp.argsort(key, stable=True)
         ch_s = ch[order]
         claim_s = claim[order]
         is_start = jnp.concatenate([jnp.ones((1,), bool), ch_s[1:] != ch_s[:-1]])[:, None]
@@ -410,7 +415,9 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
         if cmeta is not None:
             # Within-round conflict resolution + domain-state commit
             # (deferred pods stay active and retry next round).
+            prefix = accepted
             accepted = constraint_filter(jnp, accepted, choice, ps["ranks"], ps, cst, cmeta, hard_pa=hard_pa)
+            ps["deferred"] = ps["deferred"] | (prefix & ~accepted)
             stall = jnp.where(accepted.any(), jnp.int32(0), cst["stall"] + 1)
             cst = constraint_commit(jnp, accepted, choice, ps, cst, cmeta, soft_spread=soft_spread, soft_pa=soft_pa, hard_pa=hard_pa)
             cst["stall"] = stall
@@ -508,6 +515,7 @@ def assign_cycle(
         # PA bootstrap flags) derived once at cycle start and updated
         # incrementally by constraint_commit inside the round body.
         cstate = {**augment_round_state(jnp, cstate, cmeta, hard_pa=hard_pa), "stall": jnp.int32(0)}
+        ps["deferred"] = jnp.zeros((p,), bool)
 
     body = _make_round_body(
         nodes, weights, block, use_pallas, pallas_interpret, cmeta, soft_spread, soft_pa, hard_pa, tmeta
@@ -690,6 +698,7 @@ def assign_cycle_epochs(
         # Same round-carried conflict state as assign_cycle, derived once
         # (eagerly — the carry structure must be stable across epochs).
         cst = {**augment_round_state(jnp, cstate, cmeta, hard_pa=hard_pa), "stall": jnp.int32(0)}
+        ps = {**ps, "deferred": jnp.zeros((p_pad,), bool)}
     else:
         cst = cstate
     tst = tstate

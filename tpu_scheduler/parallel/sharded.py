@@ -209,6 +209,7 @@ def _build_shard_map(
             # constraint carry (ops/assign.py twin).
             cst0 = augment_round_state(jnp, cst0, cmeta, hard_pa=hard_pa)
             cst0["stall"] = jnp.int32(0)
+            cst0["deferred"] = jnp.zeros((p_tot,), bool)  # global rows the filter deferred: they claim last
             # This device's dp rows of the (replicated) pod bitmaps.
             blk_l = {k: lax.dynamic_slice_in_dim(v, dp_idx * p_local, p_local) for k, v in cpods.items()}
             g_ranks = jnp.arange(p_tot, dtype=jnp.uint32)
@@ -294,7 +295,9 @@ def _build_shard_map(
             ch_local = jnp.where(in_range, g_choice - node_base, n_local).astype(jnp.int32)
             claim = jnp.where(in_range[:, None], g_req, 0)
 
-            order = jnp.argsort(ch_local, stable=True)
+            # Deferred claimants after the others of their node (ops/assign.py).
+            key = ch_local * 2 + cst["deferred"].astype(jnp.int32) if constrained else ch_local
+            order = jnp.argsort(key, stable=True)
             ch_s = ch_local[order]
             claim_s = claim[order]
             is_start = jnp.concatenate([jnp.ones((1,), bool), ch_s[1:] != ch_s[:-1]])[:, None]
@@ -312,10 +315,13 @@ def _build_shard_map(
             # every device computes the identical result (no collective).
             if constrained:
                 gi = jnp.minimum(g_choice, n_tot - 1).astype(jnp.int32)  # clamp the non-claimant sentinel
+                prefix = accepted
                 accepted = constraint_filter(jnp, accepted, gi, g_ranks, cpods, cst, cmeta, hard_pa=hard_pa)
                 stall = jnp.where(accepted.any(), jnp.int32(0), cst["stall"] + 1)
+                deferred = cst["deferred"] | (prefix & ~accepted)
                 cst = constraint_commit(jnp, accepted, gi, cpods, cst, cmeta, soft_spread=soft_spread, soft_pa=soft_pa, hard_pa=hard_pa)
                 cst["stall"] = stall
+                cst["deferred"] = deferred
 
             # 4. capacity commit from the FILTERED accepted set; each column
             # scatter-subtracts its own nodes.

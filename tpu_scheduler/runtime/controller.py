@@ -275,6 +275,9 @@ class Scheduler:
         self._explain_snapshot: ClusterSnapshot | None = None
         self._explain_budget = 0
         self._pod_by_full_cache: tuple | None = None
+        # Seconds spent explaining, lifetime; each phase that marks pods
+        # records its share as ``<phase>/explain``.
+        self._explain_s = 0.0
         self._cycle_tag = 0  # the running cycle's number, for event stamps
         self._cycle_notes: list[str] = []  # cycle-level annotations (fallbacks)
         # Per-pod backoff queue (runtime/resilience.py): pod full name ->
@@ -321,6 +324,9 @@ class Scheduler:
         # match queries of constrained cycles the same way (self-clears on
         # term-vocabulary change; ops/constraints.pack_constraints).
         self._cons_memo: dict = {}
+        # Axis -> widest padding the constraint tensors have had, so a cycle
+        # with fewer distinct terms reuses the compiled shapes.
+        self._cons_widths: dict[str, int] = {}
         # Pipelined binding (SURVEY.md §2b PP): the binding POSTs of cycle k
         # run on a worker thread while cycle k+1 syncs/packs/solves.  The
         # assumed cache (pod full name -> node) makes in-flight bindings
@@ -598,7 +604,10 @@ class Scheduler:
             pod = self._explain_pod(pod_full)
             if pod is not None:
                 self._explain_budget -= len(snap.nodes)
+                t0 = time.perf_counter()
                 counts, feasible, total = unschedulable_reason_counts(pod, snap)
+                self._explain_s += time.perf_counter() - t0
+                self.metrics.inc("scheduler_explained_pods_total")
                 reason_value = dominant_reason(counts, feasible)
         self.metrics.inc("scheduler_unschedulable_total", labels={"reason": reason_value})
         self.recorder.record(
@@ -610,6 +619,13 @@ class Scheduler:
             detail=None if feasible is None else f"{feasible}/{total} nodes feasible pre-cycle",
         )
         self._requeue(pod_full, NoNodeFound("no feasible node this cycle"))
+
+    def _record_explain(self, since: float) -> None:
+        """Record the explaining done since ``since`` (an earlier reading of
+        ``_explain_s``) once, as ``explain`` under the open span."""
+        tr = current_trace()
+        if tr is not None and self._explain_s > since:
+            tr.record("explain", self._explain_s - since)
 
     # -- binding (main.rs:83-115) -----------------------------------------
 
@@ -1036,6 +1052,7 @@ class Scheduler:
             if pod.spec is not None and pod.spec.gang:
                 segment_gangs.setdefault(pod.spec.gang, []).append(pod)
         handled_gangs: set[str] = set()
+        explain0 = self._explain_s
         for pod in order:
             gang = pod.spec.gang if pod.spec is not None else None
             if gang:
@@ -1064,6 +1081,7 @@ class Scheduler:
                 placed.append((pod, best))
                 self._cycle_placed.append((pod, best))
                 prefilter.commit(best.name, req)
+        self._record_explain(explain0)
         return bound, unschedulable
 
     def _choose_constrained_node(
@@ -1365,8 +1383,10 @@ class Scheduler:
         tr = current_trace()
         if tr is not None and result.bindings:
             tr.record("post", self._bind_post_s - post0)
+        explain0 = self._explain_s
         for pod_full in result.unschedulable:
             self._mark_unschedulable(pod_full)
+        self._record_explain(explain0)
         return bound, len(result.unschedulable)
 
     # -- pipelined binding (SURVEY.md §2b PP) -------------------------------
@@ -1626,6 +1646,7 @@ class Scheduler:
                     packed.node_names,
                     packed.padded_nodes,
                     match_memo=self._cons_memo,
+                    widths=self._cons_widths,
                     **self.constraint_budgets,
                 )
                 if cons is not None:
@@ -1692,8 +1713,9 @@ class Scheduler:
         quantifying the gap the stall heuristic opened
         (scheduler_stall_mopup_* metrics) and closing it in the same cycle.
         Unaffected residue pods are untouched: only the constraint filter
-        defers feasible pods, so an unaffected unschedulable is already
-        proof of infeasibility."""
+        defers feasible pods, and a deferred claimant claims after the
+        others of its node from the next round on (ops/assign.py), so it
+        cannot hold an unaffected pod back until the stall stop."""
         pod_by_full = {full_name(p): p for p in batch_snapshot.pending_pods()}
         declarers = []
         passthrough = []

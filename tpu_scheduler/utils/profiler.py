@@ -104,6 +104,7 @@ SPAN_CATALOGUE = (
     "spread",      # filter sub-span: spread rank-prefix admission + cascade
     "commit",      # choose sub-span: domain-state commit of accepted claims
     "post",        # bind sub-span: the API server's binding calls, summed per bind phase
+    "explain",     # bind/mopup/constrained sub-span: the why-pending explainer, summed per phase
     "snapshot",    # rebalance sub-span: consistent packing-view build
     "plan",        # rebalance sub-span: batch selection / autoscale sub-span: catalog what-if
     "migrate",     # rebalance sub-span: breaker-gated unbinds + cordons
