@@ -15,7 +15,7 @@ import tpu_scheduler.core.predicates as P
 from tpu_scheduler.api.objects import PodAntiAffinityTerm, TopologySpreadConstraint, full_name
 from tpu_scheduler.backends.native import NativeBackend
 from tpu_scheduler.backends.tpu import TpuBackend
-from tpu_scheduler.core.snapshot import ClusterSnapshot
+from tpu_scheduler.core.snapshot import ClusterSnapshot, PlacedOverlay
 from tpu_scheduler.models.profiles import DEFAULT_PROFILE
 from tpu_scheduler.ops.constraints import UntensorizableConstraints, pack_constraints
 from tpu_scheduler.ops.pack import pack_snapshot
@@ -48,7 +48,7 @@ def _replay_validity(snap, packed, result) -> int:
             continue
         r = int(result.stats["acc_round"][i])
         by_round.setdefault(r, []).append((int(result.stats["rank"][i]), pending[i], node_by[packed.node_names[j]]))
-    placed = []
+    placed = PlacedOverlay()
     stuck = 0
     for r in sorted(by_round):
         group = sorted(by_round[r])  # rank order first — right for AA
@@ -57,9 +57,9 @@ def _replay_validity(snap, packed, result) -> int:
             remaining = []
             for rank, pod, node in group:
                 if (
-                    P.anti_affinity_ok(pod, node, snap, extra_placed=placed)
-                    and P.pod_affinity_ok(pod, node, snap, extra_placed=placed)
-                    and P.topology_spread_ok(pod, node, snap, extra_placed=placed)
+                    P.anti_affinity_ok(pod, node, snap, placed)
+                    and P.pod_affinity_ok(pod, node, snap, placed)
+                    and P.topology_spread_ok(pod, node, snap, placed)
                 ):
                     placed.append((pod, node))
                     progressed = True

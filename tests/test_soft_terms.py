@@ -20,7 +20,7 @@ from tpu_scheduler.api.objects import (
 )
 from tpu_scheduler.backends.native import NativeBackend
 from tpu_scheduler.backends.tpu import TpuBackend
-from tpu_scheduler.core.snapshot import ClusterSnapshot
+from tpu_scheduler.core.snapshot import ClusterSnapshot, PlacedOverlay
 from tpu_scheduler.models.profiles import DEFAULT_PROFILE
 from tpu_scheduler.ops.pack import pack_snapshot
 from tpu_scheduler.parallel.sharded import ShardedBackend
@@ -219,7 +219,7 @@ def test_host_sequential_phase_applies_soft_terms():
     api = FakeApiServer()
     api.load(snap.nodes, snap.pods)
     sched = Scheduler(api, NativeBackend(), policy="batch", requeue_seconds=0.0)
-    bound, unsched = sched._run_constrained_phase(snap, [pod], [])
+    bound, unsched = sched._run_constrained_phase(snap, [pod], PlacedOverlay())
     assert (bound, unsched) == (1, 0)
     placed = [p for p in api.list_pods() if p.spec.node_name]
     assert placed[0].spec.node_name == "b1"

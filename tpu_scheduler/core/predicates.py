@@ -14,10 +14,10 @@ from __future__ import annotations
 
 import enum
 from itertools import chain
-from typing import Callable, Sequence
+from typing import Callable, Iterable, Sequence
 
 from ..api.objects import LabelSelectorRequirement, Node, Pod, full_name, total_pod_resources
-from .snapshot import ClusterSnapshot, node_net_available
+from .snapshot import ClusterSnapshot, PlacedOverlay, node_net_available
 
 __all__ = [
     "InvalidNodeReason",
@@ -225,10 +225,22 @@ def node_topology_domain(node: Node, topology_key: str) -> tuple[str, str]:
     return (topology_key, v) if v is not None else ("~node", node.name)
 
 
+def _placed_matching(
+    snapshot: ClusterSnapshot, overlay: PlacedOverlay | None, namespace: str | None, match_labels
+) -> Iterable[tuple[Pod, Node]]:
+    """The snapshot's and the overlay's placements a selector with
+    ``match_labels`` in ``namespace`` can match — a superset, from their
+    indexes; every caller still tests each placement with term_matches."""
+    placed = snapshot.placed_matching(namespace, match_labels)
+    if overlay is None:
+        return placed
+    return chain(placed, overlay.matching(namespace, match_labels))
+
+
 def make_affinity_checker(
     pod: Pod,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> Callable[[Node], bool]:
     """Precompute ``pod``'s anti-affinity state into a set of blocked
     topology domains, returning an O(#keys) per-node checker.
@@ -237,8 +249,8 @@ def make_affinity_checker(
       A. none of ``pod``'s terms may match a placed pod in ``node``'s domain;
       B. no placed pod in ``node``'s domain may carry a term matching ``pod``.
     Terms are namespace-scoped: a term only sees pods sharing the namespace
-    of the pod that declares it.  ``extra_placed`` lets a sequential caller
-    overlay same-cycle commitments not yet visible in the snapshot.
+    of the pod that declares it.  ``overlay`` lets a sequential caller
+    add same-cycle commitments not yet visible in the snapshot.
 
     A node is blocked iff its domain under some relevant topology key is in
     the blocked set.  Merging keys into one set is exact: a keyless-node
@@ -253,15 +265,14 @@ def make_affinity_checker(
 
     # Direction A: domains holding a pod matched by one of my terms.
     for t in my_terms:
-        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
+        for q, qnode in _placed_matching(snapshot, overlay, my_ns, t.match_labels):
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
                 blocked.add(node_topology_domain(qnode, t.topology_key))
                 keys.add(t.topology_key)
     # Direction B: domains of placed term-carriers whose term matches me.
-    carriers = chain(
-        snapshot.placed_pods_with_terms(),
-        ((q, qn) for q, qn in extra_placed if q.spec is not None and q.spec.anti_affinity),
-    )
+    carriers = snapshot.placed_pods_with_terms()
+    if overlay is not None:
+        carriers = chain(carriers, overlay.carriers())
     for q, qnode in carriers:
         if q.metadata.namespace != my_ns:
             continue
@@ -279,19 +290,19 @@ def anti_affinity_ok(
     pod: Pod,
     node: Node,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> bool:
     """Inter-pod anti-affinity predicate (config 5; absent in the reference).
 
     One-shot form of :func:`make_affinity_checker` — see it for semantics.
     """
-    return make_affinity_checker(pod, snapshot, extra_placed)(node)
+    return make_affinity_checker(pod, snapshot, overlay)(node)
 
 
 def make_pod_affinity_checker(
     pod: Pod,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
     exclude: frozenset[str] = frozenset(),
 ) -> Callable[[Node], bool]:
     """Positive inter-pod affinity (requiredDuringScheduling co-location):
@@ -304,7 +315,7 @@ def make_pod_affinity_checker(
     a non-self-matching pod with an unmatchable term fails everywhere.
 
     Unlike anti-affinity there is no symmetric direction: a placed pod's
-    affinity terms do not constrain newcomers.  ``extra_placed`` overlays
+    affinity terms do not constrain newcomers.  ``overlay`` adds
     same-cycle commitments (the sequential host path), which also activate
     waived terms for later pods in the same cycle.  ``exclude`` removes
     placed pods (by full name) from consideration — the preemption pass
@@ -319,7 +330,7 @@ def make_pod_affinity_checker(
     term_domains: list[set[tuple[str, str]] | None] = []
     for t in my_terms:
         doms: set[tuple[str, str]] = set()
-        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
+        for q, qnode in _placed_matching(snapshot, overlay, my_ns, t.match_labels):
             if exclude and full_name(q) in exclude:
                 continue
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
@@ -344,17 +355,17 @@ def pod_affinity_ok(
     pod: Pod,
     node: Node,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> bool:
     """Positive inter-pod affinity predicate — one-shot form of
     :func:`make_pod_affinity_checker` (see it for semantics)."""
-    return make_pod_affinity_checker(pod, snapshot, extra_placed)(node)
+    return make_pod_affinity_checker(pod, snapshot, overlay)(node)
 
 
 def make_spread_checker(
     pod: Pod,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
     exclude: frozenset[str] = frozenset(),
 ) -> Callable[[Node], bool]:
     """Precompute per-constraint domain counts once, returning an
@@ -364,7 +375,7 @@ def make_spread_checker(
     pod's namespace) per *named* domain of the key; placing here must keep
     ``count(domain(node)) + 1 − min(counts) ≤ max_skew``.  A node lacking the
     key is exempt; keyless nodes' pods don't enter the counts or the min.
-    ``extra_placed`` overlays same-cycle commitments not yet in the snapshot.
+    ``overlay`` adds same-cycle commitments not yet in the snapshot.
     """
     constraints = [c for c in ((pod.spec.topology_spread or []) if pod.spec is not None else []) if c.is_hard]
     if not constraints:
@@ -372,12 +383,8 @@ def make_spread_checker(
     my_ns = pod.metadata.namespace
     per_constraint: list[tuple[str, int, dict[str, int], int]] = []
     for c in constraints:
-        counts: dict[str, int] = {}
-        for n in snapshot.nodes:
-            v = (n.metadata.labels or {}).get(c.topology_key)
-            if v is not None:
-                counts.setdefault(v, 0)
-        for q, qnode in chain(snapshot.placed_matching(my_ns, c.match_labels), extra_placed):
+        counts = dict.fromkeys(snapshot.named_domains(c.topology_key), 0)
+        for q, qnode in _placed_matching(snapshot, overlay, my_ns, c.match_labels):
             if exclude and full_name(q) in exclude:
                 continue
             v = (qnode.metadata.labels or {}).get(c.topology_key)
@@ -404,13 +411,13 @@ def topology_spread_ok(
     pod: Pod,
     node: Node,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> bool:
     """Hard topology-spread predicate (config 5; absent in the reference).
 
     One-shot form of :func:`make_spread_checker` — see it for semantics.
     """
-    return make_spread_checker(pod, snapshot, extra_placed)(node)
+    return make_spread_checker(pod, snapshot, overlay)(node)
 
 
 # --- soft (scoring) terms ---------------------------------------------------
@@ -447,7 +454,7 @@ def soft_taint_penalty(pod: Pod, node: Node) -> int:
 def make_preferred_pod_affinity_scorer(
     pod: Pod,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> Callable[[Node], float]:
     """Soft inter-pod (anti-)affinity — kube InterPodAffinity scoring: every
     placed pod (same namespace) in the candidate node's topology domain that
@@ -468,7 +475,7 @@ def make_preferred_pod_affinity_scorer(
     per_term: list[tuple[float, str, dict[tuple[str, str], int]]] = []
     for w, t in weighted:
         counts: dict[tuple[str, str], int] = {}
-        for q, qnode in chain(snapshot.placed_matching(my_ns, t.match_labels), extra_placed):
+        for q, qnode in _placed_matching(snapshot, overlay, my_ns, t.match_labels):
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
                 d = node_topology_domain(qnode, t.topology_key)
                 counts[d] = counts.get(d, 0) + 1
@@ -486,7 +493,7 @@ def make_preferred_pod_affinity_scorer(
 def make_soft_spread_scorer(
     pod: Pod,
     snapshot: ClusterSnapshot,
-    extra_placed: Sequence[tuple[Pod, Node]] = (),
+    overlay: PlacedOverlay | None = None,
 ) -> Callable[[Node], float]:
     """Penalty for the pod's ScheduleAnyway spread constraints: the count of
     matching placed pods already in the node's domain (emptier domains score
@@ -498,7 +505,7 @@ def make_soft_spread_scorer(
     per_constraint: list[tuple[str, dict[str, int]]] = []
     for c in constraints:
         counts: dict[str, int] = {}
-        for q, qnode in chain(snapshot.placed_matching(my_ns, c.match_labels), extra_placed):
+        for q, qnode in _placed_matching(snapshot, overlay, my_ns, c.match_labels):
             v = (qnode.metadata.labels or {}).get(c.topology_key)
             if v is None or q.metadata.namespace != my_ns:
                 continue

@@ -12,12 +12,13 @@ the snapshot is exactly what gets packed into device tensors (ops/pack.py).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from itertools import chain
 from typing import Iterable
 
 from ..api.objects import Node, Pod, PodResources, is_extended_resource, is_pod_bound, total_pod_resources
 from ..api.quantity import cpu_to_millis, memory_to_bytes
 
-__all__ = ["ClusterSnapshot", "node_allocatable", "node_net_available", "node_used_resources"]
+__all__ = ["ClusterSnapshot", "PlacedOverlay", "node_allocatable", "node_net_available", "node_used_resources"]
 
 
 def node_allocatable(node: Node, snapshot: "ClusterSnapshot | None" = None) -> PodResources:
@@ -81,6 +82,8 @@ class ClusterSnapshot:
     # Lazy index of ``_placed`` by (namespace, label key, label value), for
     # the inter-pod checkers' selector walks (placed_matching).
     _placed_by_label: dict | None = field(default=None, compare=False, repr=False)
+    # Lazy per-key memo of the named topology domains (named_domains).
+    _domains_by_key: dict[str, tuple[str, ...]] = field(default_factory=dict, compare=False, repr=False)
     # Lazy pending-pod memo (immutable snapshot, so one scan suffices): the
     # controller consults the pending list several times per cycle — at
     # flagship scale each uncached scan walks 200k+ pods.
@@ -138,6 +141,16 @@ class ClusterSnapshot:
             object.__setattr__(self, "_placed_by_label", index)
         return min((index.get((namespace, k, v), ()) for k, v in match_labels.items()), key=len)
 
+    def named_domains(self, topology_key: str) -> tuple[str, ...]:
+        """The distinct values of ``topology_key`` over the nodes, in node
+        order — the key's named domains, memoized per key (a hard-spread
+        checker seeds its zero counts from them once a constraint)."""
+        domains = self._domains_by_key.get(topology_key)
+        if domains is None:
+            labels = ((n.metadata.labels or {}).get(topology_key) for n in self.nodes)
+            domains = self._domains_by_key[topology_key] = tuple(dict.fromkeys(v for v in labels if v is not None))
+        return domains
+
     def pods_on_node(self, node_name: str) -> list[Pod]:
         """Snapshot equivalent of the reference's live field-selector list
         ``spec.nodeName=<node>`` (``src/predicates.rs:22-26``)."""
@@ -155,6 +168,87 @@ class ClusterSnapshot:
                 self, "_pending", [p for p in self.pods if p.status.phase == "Pending" and not is_pod_bound(p)]
             )
         return self._pending
+
+
+class PlacedOverlay:
+    """Same-cycle placements not yet in the snapshot, indexed for the
+    inter-pod checker builds that walk them beside the snapshot's own.
+
+    ``matching`` is the overlay's twin of ``ClusterSnapshot.placed_matching``
+    (the same (namespace, label pair) index and rarest-pair superset), and
+    ``carriers`` lists the placements whose pod declares anti-affinity (the
+    direction-B forbidders).  ``append`` only records: a walk first indexes
+    what was appended since the last one, so a cycle that never builds a
+    checker (a plain wave's 100k binds) pays for no index.  ``fork`` layers
+    a scratch overlay over this one: a gang trial appends to the fork and
+    drops it on reject, and this overlay's placements never change.  Each
+    walk tallies the placements it hands out (walked) and the overlay's
+    whole length (offered, what an unindexed walk visits); forks share
+    their root's tally, which the caller folds once a phase (``take_tally``).
+    """
+
+    __slots__ = ("_base", "layer", "_indexed", "_by_label", "_carriers", "_tally")
+
+    def __init__(self, placements: Iterable[tuple[Pod, Node]] = (), base: "PlacedOverlay | None" = None):
+        self._base = base
+        self.layer: list[tuple[Pod, Node]] = list(placements)  # this layer's placements, in append order
+        self._indexed = 0
+        self._by_label: dict[tuple, list] = {}
+        self._carriers: list[tuple[Pod, Node]] = []
+        self._tally = base._tally if base is not None else [0, 0]
+
+    def append(self, placement: tuple[Pod, Node]) -> None:
+        self.layer.append(placement)
+
+    def extend(self, placements: Iterable[tuple[Pod, Node]]) -> None:
+        self.layer.extend(placements)
+
+    def fork(self) -> "PlacedOverlay":
+        return PlacedOverlay(base=self)
+
+    def __len__(self) -> int:
+        return len(self.layer) + (len(self._base) if self._base is not None else 0)
+
+    def __iter__(self):
+        return chain(self._base or (), self.layer)
+
+    def _indexed_layers(self) -> list["PlacedOverlay"]:
+        """The layers, root first, each indexed up to its last placement."""
+        layers = [self] if self._base is None else [*self._base._indexed_layers(), self]
+        for placement in self.layer[self._indexed:]:
+            q = placement[0]
+            ns = q.metadata.namespace
+            for k, v in (q.metadata.labels or {}).items():
+                self._by_label.setdefault((ns, k, v), []).append(placement)
+            if q.spec is not None and q.spec.anti_affinity:
+                self._carriers.append(placement)
+        self._indexed = len(self.layer)
+        return layers
+
+    def _walk(self, parts: list) -> Iterable[tuple[Pod, Node]]:
+        self._tally[0] += sum(map(len, parts))
+        self._tally[1] += len(self)
+        return parts[0] if len(parts) == 1 else chain.from_iterable(parts)
+
+    def matching(self, namespace: str | None, match_labels: dict[str, str] | None) -> Iterable[tuple[Pod, Node]]:
+        """The placements in ``namespace`` whose pod carries the rarest pair
+        of ``match_labels`` (every placement when it has none): a superset of
+        those a selector with these labels matches."""
+        layers = self._indexed_layers()
+        if not match_labels:
+            return self._walk([o.layer for o in layers])
+        pairs = [(namespace, k, v) for k, v in match_labels.items()]
+        return self._walk([min((o._by_label.get(pair, ()) for pair in pairs), key=len) for o in layers])
+
+    def carriers(self) -> Iterable[tuple[Pod, Node]]:
+        """The placements whose pod declares anti-affinity terms."""
+        return self._walk([o._carriers for o in self._indexed_layers()])
+
+    def take_tally(self) -> tuple[int, int]:
+        """(walked, offered) since the last call; resets both."""
+        walked, offered = self._tally
+        self._tally[:] = [0, 0]
+        return walked, offered
 
 
 def node_net_available(snapshot: ClusterSnapshot, node: Node) -> PodResources:

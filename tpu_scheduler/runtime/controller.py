@@ -57,7 +57,7 @@ from ..core.predicates import (
     term_matches,
     topology_spread_ok,
 )
-from ..core.snapshot import ClusterSnapshot, node_allocatable, node_net_available, node_used_resources
+from ..core.snapshot import ClusterSnapshot, PlacedOverlay, node_allocatable, node_net_available, node_used_resources
 from ..errors import BackendUnavailable, CreateBindingFailed, NoNodeFound, SchedulerError
 from ..models.profiles import DEFAULT_PROFILE, SchedulingProfile
 from ..ops.pack import extend_node_vocabs, pack_snapshot, repack_incremental
@@ -1025,7 +1025,7 @@ class Scheduler:
         return score
 
     def _run_constrained_phase(
-        self, snapshot: ClusterSnapshot, constrained: list[Pod], placed: list[tuple[Pod, Node]]
+        self, snapshot: ClusterSnapshot, constrained: list[Pod], placed: PlacedOverlay
     ) -> tuple[int, int]:
         """Schedule affinity-constrained pods sequentially with the full
         predicate chain: exhaustive over nodes (not sampled), best score
@@ -1082,15 +1082,24 @@ class Scheduler:
                 self._cycle_placed.append((pod, best))
                 prefilter.commit(best.name, req)
         self._record_explain(explain0)
+        self._fold_overlay_tally(placed)
         return bound, unschedulable
 
+    def _fold_overlay_tally(self, overlay: PlacedOverlay) -> None:
+        """Count a phase's inter-pod checker walks of the same-cycle overlay:
+        the placements they visited, and those an unindexed walk would have."""
+        walked, offered = overlay.take_tally()
+        if offered:
+            self.metrics.inc("scheduler_checker_overlay_walked_total", walked)
+            self.metrics.inc("scheduler_checker_overlay_offered_total", offered)
+
     def _choose_constrained_node(
-        self, pod: Pod, snapshot: ClusterSnapshot, ledger: dict, placed: list, prefilter, weights, req: PodResources
+        self, pod: Pod, snapshot: ClusterSnapshot, ledger: dict, placed: PlacedOverlay, prefilter, weights, req: PodResources
     ) -> Node | None:
         """Best-scoring feasible node for one pod through the exact scalar
         chain (exhaustive over the prefilter's fitting nodes).  ``ledger``
         and ``placed`` are whatever state the caller is working against —
-        the phase's real state, or a gang trial's scratch copies; the
+        the phase's real state, or a gang trial's scratch ledger and fork; the
         prefilter may lag a scratch ledger (it only prunes — the ledger-
         aware scalar chain re-checks resources exactly)."""
         # Precompute the pod's affinity/spread state once — the node loop
@@ -1120,7 +1129,7 @@ class Scheduler:
         snapshot: ClusterSnapshot,
         gang: str,
         members_here: list[Pod],
-        placed: list,
+        placed: PlacedOverlay,
         ledger: dict,
         prefilter,
         weights,
@@ -1150,9 +1159,10 @@ class Scheduler:
                 self._requeue(full_name(p), "gang split across scheduling scopes; retry as a unit")
             return 0, len(members_here)
         # Trial pass against scratch state (PodResources is mutated with +=,
-        # so the ledger copy must be value-deep).
+        # so the ledger copy must be value-deep; the trial's placements go
+        # to a fork, dropped on reject).
         trial_ledger = {k: v.copy() for k, v in ledger.items()}
-        trial_placed = list(placed)
+        trial_placed = placed.fork()
         chosen: list[tuple[Pod, Node, PodResources]] = []
         failed_at: str | None = None
         for pod in sorted(members_here, key=_pod_priority, reverse=True):
@@ -1364,7 +1374,7 @@ class Scheduler:
             self._cycle_notes.append(f"backend-fallback: {backend.name} -> {self.fallback_backend.name} ({e})")
             return self.fallback_backend.schedule(packed, self.profile)
 
-    def _bind_result(self, batch_snapshot: ClusterSnapshot, result, placed: list[tuple[Pod, Node]]) -> tuple[int, int]:
+    def _bind_result(self, batch_snapshot: ClusterSnapshot, result, placed: PlacedOverlay) -> tuple[int, int]:
         """POST a cycle result's bindings; requeue its unschedulables.  The
         time spent inside the API calls is recorded once, as ``post`` under
         the open ``bind`` span."""
@@ -1551,7 +1561,7 @@ class Scheduler:
                 pods.append(p)
         return ClusterSnapshot.build(snapshot.nodes, pods)
 
-    def _run_routed_cycle(self, snapshot: ClusterSnapshot, part, placed: list[tuple[Pod, Node]]) -> tuple[int, int, int]:
+    def _run_routed_cycle(self, snapshot: ClusterSnapshot, part, placed: PlacedOverlay) -> tuple[int, int, int]:
         """Expert-parallel cycle (parallel/routing.py): per-pool shards pack
         and solve CONCURRENTLY (each shard on its own device when the
         backend has several — JAX async dispatch overlaps the solves), then
@@ -1610,7 +1620,7 @@ class Scheduler:
     def _schedule_batch(
         self,
         batch_snapshot: ClusterSnapshot,
-        placed: list[tuple[Pod, Node]],
+        placed: PlacedOverlay,
         with_constraints: bool = False,
         mopup_candidates: set[str] | None = None,
     ) -> tuple[int, int, int]:
@@ -1697,7 +1707,7 @@ class Scheduler:
         return min(self.MOPUP_MAX, max(16, self.MOPUP_WORK // max(1, n_nodes)))
 
     def _constraint_stall_mopup(
-        self, batch_snapshot: ClusterSnapshot, result, placed: list, candidates: set[str]
+        self, batch_snapshot: ClusterSnapshot, result, placed: PlacedOverlay, candidates: set[str]
     ):
         """Sequential completeness pass over a constraint auction's residue
         (VERDICT r3 #7).  The auction stops after STALL_ROUNDS consecutive
@@ -1736,17 +1746,17 @@ class Scheduler:
             declarers = declarers[:cap]
         # The sequential phase must see the auction's accepted placements as
         # consumed capacity/domain state; they are not in ``placed`` yet
-        # (binding happens after), so seed a working copy.
+        # (binding happens after), so seed a fork of it.
         node_by_name = {n.name: n for n in batch_snapshot.nodes}
-        seeded = list(placed)
+        seeded = placed.fork()
         for pod_full, node_name in result.bindings:
             pod_obj, node_obj = pod_by_full.get(pod_full), node_by_name.get(node_name)
             if pod_obj is not None and node_obj is not None:
                 seeded.append((pod_obj, node_obj))
         self.metrics.inc("scheduler_stall_mopup_attempted_total", len(declarers))
-        seeded_len = len(seeded)
+        seeded_len = len(seeded.layer)
         bound, unsched = self._run_constrained_phase(batch_snapshot, declarers, seeded)
-        placed.extend(seeded[seeded_len:])  # mop-up placements are cycle placements
+        placed.extend(seeded.layer[seeded_len:])  # mop-up placements are cycle placements
         if bound:
             self.metrics.inc("scheduler_stall_mopup_bound_total", bound)
             logger.info(
@@ -1762,7 +1772,7 @@ class Scheduler:
         with span("queue"):
             pending = snapshot.pending_pods()
             _, constrained = self._split_affinity_pending(snapshot, pending)
-        placed: list[tuple[Pod, Node]] = []
+        placed = PlacedOverlay()
         if not constrained:
             # Expert-parallel routing: pods pinned to node pools schedule as
             # independent per-pool shards (parallel/routing.py); constrained
@@ -1914,7 +1924,7 @@ class Scheduler:
         # snapshot predates them, and ignoring them would let the pass bind
         # onto capacity the main pass already consumed (oversubscription).
         extra_used: dict[str, PodResources] = {}
-        placed_overlay: list[tuple[Pod, Node]] = list(self._cycle_placed)
+        placed_overlay = PlacedOverlay(self._cycle_placed)
         for q, qn in self._cycle_placed:
             u = extra_used.setdefault(qn.name, PodResources())
             u += total_pod_resources(q)
@@ -2106,6 +2116,7 @@ class Scheduler:
                 logger.warning(
                     "preemptor %s failed to bind after %d evictions; retrying next cycle", full_name(pod), len(victims)
                 )
+        self._fold_overlay_tally(placed_overlay)
         return bound, victims_total
 
     # -- sample policy (reference main.rs:49-71) ---------------------------
@@ -2115,7 +2126,7 @@ class Scheduler:
         pod: Pod,
         snapshot: ClusterSnapshot,
         ledger: dict[str, PodResources],
-        placed: list[tuple[Pod, Node]],
+        placed: PlacedOverlay,
     ) -> Node | None:
         nodes = self.reflector.nodes.state()
         if not nodes:
@@ -2134,7 +2145,7 @@ class Scheduler:
         node: Node,
         snapshot: ClusterSnapshot,
         ledger: dict[str, PodResources],
-        placed: list[tuple[Pod, Node]],
+        placed: PlacedOverlay,
         affinity_checker=None,
         spread_checker=None,
         pod_affinity_checker=None,
@@ -2162,19 +2173,19 @@ class Scheduler:
             if not pred(pod, node, snapshot):
                 return reason
         affinity_fine = (
-            affinity_checker(node) if affinity_checker is not None else anti_affinity_ok(pod, node, snapshot, extra_placed=placed)
+            affinity_checker(node) if affinity_checker is not None else anti_affinity_ok(pod, node, snapshot, placed)
         )
         if not affinity_fine:
             return InvalidNodeReason.ANTI_AFFINITY_VIOLATION
         pa_fine = (
             pod_affinity_checker(node)
             if pod_affinity_checker is not None
-            else pod_affinity_ok(pod, node, snapshot, extra_placed=placed)
+            else pod_affinity_ok(pod, node, snapshot, placed)
         )
         if not pa_fine:
             return InvalidNodeReason.POD_AFFINITY_UNSATISFIED
         spread_fine = (
-            spread_checker(node) if spread_checker is not None else topology_spread_ok(pod, node, snapshot, extra_placed=placed)
+            spread_checker(node) if spread_checker is not None else topology_spread_ok(pod, node, snapshot, placed)
         )
         if not spread_fine:
             return InvalidNodeReason.TOPOLOGY_SPREAD_VIOLATION
@@ -2182,7 +2193,7 @@ class Scheduler:
 
     def _run_sample_cycle(self, snapshot: ClusterSnapshot, pending: list[Pod]) -> tuple[int, int]:
         ledger: dict[str, PodResources] = {}
-        placed: list[tuple[Pod, Node]] = []
+        placed = PlacedOverlay()
         bound = 0
         unschedulable = 0
         refused_gangs: set[str] = set()
@@ -2214,6 +2225,7 @@ class Scheduler:
                 committed += total_pod_resources(pod)
                 placed.append((pod, node))
                 self._cycle_placed.append((pod, node))
+        self._fold_overlay_tally(placed)
         return bound, unschedulable
 
     def _pre_cycle_overlay(self, snapshot: ClusterSnapshot) -> ClusterSnapshot:
