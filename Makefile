@@ -4,19 +4,19 @@
 
 PY ?= python
 
-.PHONY: check lint analyze test native bench sim-smoke profile-smoke constrained-smoke delta-smoke defrag-smoke train-smoke latency-smoke elasticity-smoke protocol-smoke fuzz-smoke jit-stability-smoke clean
+.PHONY: check lint analyze test native sim-smoke profile-smoke constrained-smoke delta-smoke defrag-smoke train-smoke latency-smoke elasticity-smoke protocol-smoke fuzz-smoke jit-stability-smoke clean
 
 check: lint test profile-smoke constrained-smoke delta-smoke defrag-smoke train-smoke latency-smoke elasticity-smoke protocol-smoke fuzz-smoke jit-stability-smoke
 
 lint: analyze
-	$(PY) -m compileall -q tpu_scheduler tests scripts bench.py __graft_entry__.py
+	$(PY) -m compileall -q tpu_scheduler tests scripts __graft_entry__.py
 
 # The whole static-analysis policy (scripts/analyze/): ported hygiene rules
 # plus THRD lock discipline, JAXP jit purity, DTRM sim determinism, SHPE
 # shape contracts, EXCP failure-class closure, and the baseline gate (fails
-# on new findings and on stale baseline entries).  The report artifact is
-# consumed by bench.py provenance; --budget asserts the suite stays the
-# fast part of this gate (pre-commit uses the --changed-only fast path).
+# on new findings and on stale baseline entries).  --budget asserts the
+# suite stays the fast part of this gate (pre-commit uses the
+# --changed-only fast path).
 analyze:
 	$(PY) -m scripts.analyze --json-out .analyze_report.json --budget 5
 
@@ -108,13 +108,6 @@ jit-stability-smoke:
 # C++ shim (optional; ops/native_ext.py gates on its presence)
 native:
 	$(MAKE) -C native
-
-# Regression-gated: fails (exit 2) when the flagship min-of-repeats exceeds
-# the previous round's recorded number by >1.3x.  The driver's end-of-round
-# run calls bench.py directly without the gate — a regressed number on
-# record still beats none.
-bench:
-	$(PY) bench.py --fail-regression-threshold 1.3
 
 clean:
 	find . -name __pycache__ -type d -exec rm -rf {} + 2>/dev/null || true

@@ -14,7 +14,7 @@ import pathlib
 from dataclasses import dataclass
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
-DEFAULT_PATHS = ["tpu_scheduler", "tests", "bench.py", "__graft_entry__.py", "scripts"]
+DEFAULT_PATHS = ["tpu_scheduler", "tests", "__graft_entry__.py", "scripts"]
 
 
 @dataclass(frozen=True)

@@ -15,8 +15,7 @@ Options:
     --json               machine-readable report on stdout (findings with a
                          baselined flag, plus new/stale arrays) for CI
                          annotation
-    --json-out FILE      additionally write the JSON report to FILE (the
-                         artifact bench.py folds into its provenance row)
+    --json-out FILE      additionally write the JSON report to FILE
     --budget SECONDS     fail (exit 1) when the whole run exceeds SECONDS —
                          the make-check guarantee that analysis never
                          becomes the slow part of the gate
@@ -237,10 +236,10 @@ def main(argv: list[str]) -> int:
             "budget_s": budget,
             "changed_only": changed_only,
             # Per-machine model-check stats (empty when MODL did not run,
-            # e.g. --changed-only or a --rule subset); bench.py provenance.
+            # e.g. --changed-only or a --rule subset).
             "modelcheck": dict(modelcheck.LAST_STATS),
             # Bucket/hotpath contract coverage (empty when JITC did not
-            # run); bench.py provenance.
+            # run).
             "jitc": dict(jitc.LAST_STATS),
         }
     if json_out and report is not None:

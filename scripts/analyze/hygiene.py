@@ -4,7 +4,7 @@ plus basic mechanical hygiene, on the stdlib so the gate runs in the build
 image (which carries no installable linter).
 
 Scope: the WHOLE analyzed tree — ``tpu_scheduler/``, ``tests/``,
-``scripts/``, ``bench.py``, ``__graft_entry__.py`` (every file the driver
+``scripts/``, ``__graft_entry__.py`` (every file the driver
 loads; there is no package filter here, and tests/test_analyze.py pins that
 a violation seeded under tests/ or scripts/ is flagged)."""
 

@@ -23,8 +23,7 @@ FILE_SCOPED, like EXCP): a spec edit anywhere re-verifies that machine
 regardless of which files changed.
 
 ``LAST_STATS`` exposes per-machine exploration stats (states, transitions,
-violations) after each run; the driver folds it into ``--json-out`` and
-bench.py records it as provenance.
+violations) after each run; the driver folds it into ``--json-out``.
 """
 
 from __future__ import annotations

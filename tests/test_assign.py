@@ -136,46 +136,40 @@ def test_no_pending_pods():
     assert result.rounds == 0
 
 
-# --- epoch-shrinking driver (perf path of TpuBackend) ------------------------
+# --- the TpuBackend auction (size chain) against the native oracle ----------
 
 
 @pytest.mark.parametrize(
     "n_nodes,n_pending,seed,kw",
     [
-        (16, 200, 0, {}),  # contention: many rounds, several shrinks
-        (64, 500, 1, {"selector_fraction": 0.4}),
+        (16, 200, 0, {}),  # contention: many rounds
+        (64, 500, 1, {"selector_fraction": 0.4}),  # 512 rows: the chain drops to a 256-row stage
         (24, 120, 2, {"soft_taint_fraction": 0.3, "preferred_affinity_fraction": 0.3}),
         (24, 160, 5, {"extended_fraction": 0.3}),  # [·,3] resource tensors
     ],
 )
-def test_epoch_driver_matches_monolithic(n_nodes, n_pending, seed, kw):
-    """assign_cycle_epochs must be bit-identical to assign_cycle: same
-    assignments, same rounds, same remaining capacity, same acc_round."""
-    import jax.numpy as jnp
-
+def test_size_chain_matches_native(n_nodes, n_pending, seed, kw):
+    """assign_cycle through TpuBackend, with a block small enough that the
+    pod arrays are block-padded, is bit-identical to the native oracle:
+    same bindings, same rounds, same acc_round."""
+    from tpu_scheduler.backends.tpu import TpuBackend
     from tpu_scheduler.models.profiles import DEFAULT_PROFILE
-    from tpu_scheduler.ops.assign import assign_cycle, assign_cycle_epochs, split_device_arrays
 
     snap = synth_cluster(n_nodes=n_nodes, n_pending=n_pending, n_bound=n_nodes, seed=seed, **kw)
     packed = pack_snapshot(snap, pod_block=16, node_block=16)
-    a = {k: jnp.asarray(v) for k, v in packed.device_arrays().items()}
-    nodes, pods = split_device_arrays(a)
-    w = jnp.asarray(DEFAULT_PROFILE.weights())
-    mono = assign_cycle(nodes, pods, w, max_rounds=64, block=32)
-    epoch = assign_cycle_epochs(nodes, pods, w, max_rounds=64, block=32)
-    np.testing.assert_array_equal(np.asarray(mono[0]), np.asarray(epoch[0]))  # assigned
-    assert int(mono[1]) == int(epoch[1])  # rounds
-    np.testing.assert_array_equal(np.asarray(mono[2]), np.asarray(epoch[2]))  # avail
-    np.testing.assert_array_equal(np.asarray(mono[3]), np.asarray(epoch[3]))  # acc_round
-    np.testing.assert_array_equal(np.asarray(mono[4]), np.asarray(epoch[4]))  # rank_of
+    profile = DEFAULT_PROFILE.with_(max_rounds=64, pod_block=32)
+    rn = NativeBackend().schedule(packed, profile)
+    rt = TpuBackend().schedule(packed, profile)
+    assert rn.bindings == rt.bindings
+    assert rn.rounds == rt.rounds
+    np.testing.assert_array_equal(rn.stats["acc_round"], rt.stats["acc_round"])
 
 
-def test_epoch_driver_matches_monolithic_constrained():
-    """Constraint cycles (AA + spread + ScheduleAnyway) through the epoch
-    driver: identical to the monolithic path and the native oracle."""
+def test_constrained_tpu_matches_native():
+    """Constraint cycles (AA + spread + ScheduleAnyway) through TpuBackend:
+    identical to the native oracle."""
     from dataclasses import replace
 
-    from tpu_scheduler.backends.native import NativeBackend
     from tpu_scheduler.backends.tpu import TpuBackend
     from tpu_scheduler.models.profiles import DEFAULT_PROFILE
     from tpu_scheduler.ops.constraints import pack_constraints
@@ -189,11 +183,10 @@ def test_epoch_driver_matches_monolithic_constrained():
     assert cons is not None
     packed = replace(packed, constraints=cons)
     rn = NativeBackend().schedule(packed, DEFAULT_PROFILE)
-    for driver in ("monolithic", "epochs"):
-        rt = TpuBackend().schedule(packed, DEFAULT_PROFILE.with_(driver=driver))
-        assert rn.bindings == rt.bindings, driver
-        assert rn.rounds == rt.rounds, driver
-        assert (rn.stats["acc_round"] == rt.stats["acc_round"]).all(), driver
+    rt = TpuBackend().schedule(packed, DEFAULT_PROFILE)
+    assert rn.bindings == rt.bindings
+    assert rn.rounds == rt.rounds
+    assert (rn.stats["acc_round"] == rt.stats["acc_round"]).all()
 
 
 def test_throughput_profile_round_count_stays_low():

@@ -4,7 +4,7 @@ exact by sharing the mask/score expression trees and mirroring the commit
 arithmetic (saturating scan ≡ int64+clamp).
 
 Runs JAX on the virtual 8-device CPU platform (tests/conftest.py); the same
-jitted code path runs on real TPU in bench.py.
+jitted code path runs on a real TPU in chip_smoke.py and the benchmark.
 """
 
 import numpy as np

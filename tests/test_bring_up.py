@@ -11,7 +11,6 @@ jax = pytest.importorskip("jax")
 
 from tpu_scheduler.backends.native import NativeBackend  # noqa: E402
 from tpu_scheduler.errors import CompileFailed  # noqa: E402
-from tpu_scheduler.models.profiles import SchedulingProfile  # noqa: E402
 from tpu_scheduler.backends.tpu import TpuBackend  # noqa: E402
 from tpu_scheduler.ops.pack import pack_snapshot  # noqa: E402
 from tpu_scheduler.parallel.mesh import make_mesh  # noqa: E402
@@ -52,10 +51,9 @@ def _run_with_fallback(kind, snap):
     """One controller cycle with the native fallback configured; returns
     (raised exception or None, scheduler_backend_fallbacks_total)."""
     backend = ShardedBackend(make_mesh(tp=2), use_pallas=False) if kind == "tpu-sharded" else TpuBackend(use_pallas=False)
-    profile = SchedulingProfile(driver="epochs" if kind == "tpu-epochs" else "monolithic")
     api = FakeApiServer()
     api.load(snap.nodes, snap.pods)
-    sched = Scheduler(api, backend, profile=profile, fallback_backend=NativeBackend())
+    sched = Scheduler(api, backend, fallback_backend=NativeBackend())
     try:
         try:
             sched.run_cycle()
@@ -67,7 +65,7 @@ def _run_with_fallback(kind, snap):
     return err, sched.metrics.snapshot().get("scheduler_backend_fallbacks_total", 0)
 
 
-@pytest.mark.parametrize("kind", ["tpu", "tpu-epochs", "tpu-sharded"])
+@pytest.mark.parametrize("kind", ["tpu", "tpu-sharded"])
 def test_compile_failure_raises_and_execute_fault_falls_back(kind, monkeypatch):
     """A program the compiler refuses (a Mosaic rejection arrives as
     JaxRuntimeError from compile) raises CompileFailed through the
@@ -96,7 +94,7 @@ def test_compile_failure_raises_and_execute_fault_falls_back(kind, monkeypatch):
     assert fallbacks == 1
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_no_tpu_exits_without_result(script):
     """Held to the CPU, neither entry point prints a result: non-zero exit,
     no ``"ok": true`` line and no metric line."""

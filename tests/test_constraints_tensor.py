@@ -593,8 +593,17 @@ def test_dense_boundary_parity(monkeypatch):
         "term_uses_dom": (rng.random((t, d)) < 0.4).astype(np.float32),
         "sp_uses_dom": np.zeros((8, d), np.float32),
         "sp_skew": np.zeros((8,), np.float32),
+        "sp_dom_sel": np.eye(d, dtype=np.float32),
     }
-    state = {"sp_counts": np.zeros((8, d), np.float32)}
+    state = C.augment_round_state(
+        np,
+        {
+            "sp_counts": np.zeros((8, d), np.float32),
+            "pa_dom_m": np.zeros((0, d), np.float32),
+            "pa_node_m": np.zeros((0, n), np.float32),
+        },
+        meta,
+    )
     args = []
     for seed in range(3):
         r = np.random.default_rng(seed)

@@ -84,7 +84,7 @@ def test_constraint_commit_updates_preference_counts():
     directly — deleting the ppa commit logic must fail this test."""
     import numpy as np
 
-    from tpu_scheduler.ops.constraints import constraint_commit, pack_constraints, round_blocked_masks
+    from tpu_scheduler.ops.constraints import augment_round_state, constraint_commit, pack_constraints, round_blocked_masks
     from tpu_scheduler.ops.pack import pack_snapshot
 
     keyless = make_node("bare", cpu="8", memory="32Gi")  # no zone label -> fine domain
@@ -104,9 +104,8 @@ def test_constraint_commit_updates_preference_counts():
     choice = np.zeros((p,), np.int32)
     choice[0] = 1  # cache-0 -> n1 (zone z1)
     choice[1] = 6  # cache-1 -> bare (keyless -> fine twin)
-    state = constraint_commit(
-        np, accepted, choice, cons.pod_arrays(), cons.state_arrays(), cons.meta_arrays(), soft_pa=True
-    )
+    state0 = augment_round_state(np, cons.state_arrays(), cons.meta_arrays())
+    state = constraint_commit(np, accepted, choice, cons.pod_arrays(), state0, cons.meta_arrays(), soft_pa=True)
     ndc = cons.node_dom_c  # [N, D]
     z1_col = int(np.argmax(ndc[1]))  # n1's one-hot domain column
     assert state["ppa_dom_cnt"][0, z1_col] == 1.0, "coarse domain count not bumped"

@@ -215,7 +215,7 @@ def test_native_tpu_parity_with_topology_both_drivers():
     topo = pack_topology(compiled, snap.pending_pods(), packed.padded_pods, packed.node_names, packed.padded_nodes)
     packed = replace(packed, topology=topo)
     tb = TpuBackend(use_pallas=False)
-    for profile in (DEFAULT_PROFILE, PROFILES["throughput"], DEFAULT_PROFILE.with_(driver="epochs")):
+    for profile in (DEFAULT_PROFILE, PROFILES["throughput"]):
         rn = NativeBackend().schedule(packed, profile)
         rt = tb.schedule(packed, profile)
         assert rn.bindings == rt.bindings, profile.name

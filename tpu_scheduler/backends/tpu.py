@@ -15,7 +15,7 @@ import numpy as np
 
 from ..errors import BackendUnavailable
 from ..models.profiles import SchedulingProfile
-from ..ops.assign import assign_cycle, assign_cycle_epochs, split_device_arrays
+from ..ops.assign import assign_cycle, split_device_arrays
 from ..ops.pack import PackedCluster
 from ..utils.compile_cache import aot_call
 from ..utils.profiler import install_jax_profile_hooks, record_transfer
@@ -235,15 +235,12 @@ class TpuBackend(SchedulingBackend):
             pods.update({k: self._put(v) for k, v in topo.pod_arrays().items()})
             tmeta = {k: self._put(v) for k, v in topo.meta_arrays().items()}
             tstate = {k: jax.device_put(v, self.device) for k, v in topo.state_arrays().items()}
-        # Driver choice (profiles.py `driver`): monolithic keeps the whole
-        # auction in one jit program — one host sync per cycle, no jit-
-        # boundary relayouts — and since the in-jit static size chain
-        # (assign_cycle) it also shrinks the per-round cost with the active
-        # count, so it beats the host-driven epoch driver on BOTH cycle
-        # shapes (measurements in profiles.py).  Both drivers are
-        # bit-identical in results (tests/test_assign.py).  Both compile
-        # ahead of time (aot_call): a program the compiler refuses raises
-        # CompileFailed, outside the runtime-fault contract in assign().
+        # The whole auction is one jit program — one host sync per cycle,
+        # no jit-boundary relayouts — and its in-jit static size chain
+        # (assign_cycle) shrinks the per-round cost with the active count.
+        # It compiles ahead of time (aot_call): a program the compiler
+        # refuses raises CompileFailed, outside the runtime-fault contract
+        # in assign().
         static = {
             "max_rounds": profile.max_rounds,
             "block": profile.pod_block,
@@ -253,10 +250,7 @@ class TpuBackend(SchedulingBackend):
             "hard_pa": cons is not None and cons.n_pa_terms > 0,
         }
         operands = {"cmeta": cmeta, "cstate": cstate, "tmeta": tmeta, "tstate": tstate}
-        if profile.driver == "epochs":
-            out = assign_cycle_epochs(nodes, pods, weights, **static, **operands)
-        else:
-            out = aot_call(assign_cycle, static, nodes, pods, weights, **operands)
+        out = aot_call(assign_cycle, static, nodes, pods, weights, **operands)
         assigned, rounds, _avail, acc_round, rank_of = out
         # ONE device→host fetch for the whole result: assigned/acc_round/
         # rank_of/rounds ride home stacked in a single [4, P] transfer

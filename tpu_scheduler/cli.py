@@ -66,13 +66,7 @@ def build_parser() -> argparse.ArgumentParser:
         default=None,
         metavar="PATH",
         help="load the scoring profile from a tuned-profile JSON artifact (learn/profiles schema; "
-        "overrides --profile; --driver/--max-rounds/--pool-key/--preemption still apply on top)",
-    )
-    p.add_argument(
-        "--driver",
-        choices=["auto", "monolithic", "epochs"],
-        default=None,
-        help="auction driver override (profiles.SchedulingProfile.driver): auto/monolithic = one jit program with the in-jit size chain; epochs = host-driven size shrinking for boundary-cheap environments",
+        "overrides --profile; --max-rounds/--pool-key/--preemption still apply on top)",
     )
     p.add_argument("--max-rounds", type=int, default=None, help="auction round cap override (profiles default: 32)")
     p.add_argument("--leader-elect", action="store_true", help="lease-based leader election: only the lease holder schedules; standbys keep caches warm and take over on leader loss")
@@ -289,8 +283,6 @@ def build_scheduler(args: argparse.Namespace):
         profile = SchedulingProfile.from_file(args.profile_file)
     else:
         profile = PROFILES[args.profile]
-    if args.driver is not None:
-        profile = profile.with_(driver=args.driver)
     if args.max_rounds is not None:
         profile = profile.with_(max_rounds=args.max_rounds)
     if args.pool_key:

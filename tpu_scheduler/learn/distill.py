@@ -5,8 +5,8 @@ A training run's output is just a ``SchedulingProfile`` serialized as the
 versioned JSON artifact (``models/profiles.py`` schema), loadable via
 ``SchedulingProfile.from_file`` / CLI ``--profile-file`` — the tuned
 weights ride the existing fused choose path (native, jit, and Pallas
-variants) exactly like the defaults did, so the steady-state delta-cycle
-bench is unchanged by construction (bench.py ``policy_row`` holds that).
+variants) exactly like the defaults did, so the steady-state delta cycle
+costs the same by construction.
 
 ``provenance`` makes every artifact auditable: the full ``SearchConfig``
 echo (one seed reproduces the run), the objective version it was trained

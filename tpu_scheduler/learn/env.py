@@ -77,7 +77,7 @@ ACTION_KNOBS = (
 
 def action_profile(base, vec):
     """Clip a raw policy vector into ``ACTION_KNOBS`` bounds and graft it
-    onto ``base`` (preemption/driver/blocks untouched)."""
+    onto ``base`` (preemption/blocks untouched)."""
     # shape: (base: obj, vec: obj) -> obj
     if len(vec) != len(ACTION_KNOBS):
         raise ValueError(f"action vector has {len(vec)} entries, expected {len(ACTION_KNOBS)}")
@@ -190,7 +190,7 @@ class SchedulerEnv:
 
     def base_profile(self):
         """The profile the fleet is currently scheduling with (the action
-        vector grafts onto this, preserving preemption/driver)."""
+        vector grafts onto this, preserving preemption)."""
         # shape: (self: obj) -> obj
         if self._ctx is None:
             raise RuntimeError("call reset() first")

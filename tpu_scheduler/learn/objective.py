@@ -40,7 +40,7 @@ __all__ = [
 ]
 
 # Bump on any formula or weight change: tuned artifacts record the version
-# they were trained against, and bench.py refuses cross-version comparison.
+# they were trained against.
 OBJECTIVE_VERSION = 1
 
 # The closed (component name -> weight) schema.  Order is the reporting

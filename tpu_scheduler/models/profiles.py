@@ -58,16 +58,6 @@ class SchedulingProfile:
     # node in the right slice over a better-packed node a rack away.
     # 0 disables the term (topology-blind gang scoring).
     gang_locality_weight: float = 64.0
-    # Auction driver (backends/tpu.py): "monolithic" (and "auto", the
-    # default) runs the whole auction as ONE jit program containing a
-    # static size chain — the round body at quartering array sizes with
-    # on-device result folding (ops/assign.py assign_cycle) — so the
-    # per-round accept/compact/constraint cost shrinks with the active
-    # count at zero host syncs.  "epochs" is the host-driven size-shrinking
-    # driver (assign_cycle_epochs), kept for environments with cheap jit
-    # boundaries; each of its re-entries pays a narrow-operand relayout
-    # plus a host sync.
-    driver: str = "auto"
     # Expert-parallel routing (parallel/routing.py): node label whose values
     # partition the cluster into per-pool scheduling shards; None = off.
     pool_key: str | None = None
@@ -76,10 +66,6 @@ class SchedulingProfile:
     # (kube PostFilter semantics).  Off by default: the synthetic cluster
     # has no controllers to recreate evicted pods.
     preemption: bool = False
-
-    def __post_init__(self):
-        if self.driver not in ("auto", "monolithic", "epochs"):
-            raise ValueError(f"unknown driver {self.driver!r} (expected 'auto', 'monolithic' or 'epochs')")
 
     def weights(self) -> np.ndarray:
         return np.array(

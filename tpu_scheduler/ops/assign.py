@@ -56,10 +56,8 @@ from .masks import feasibility_block
 from .pack import INT32_MAX, STALL_ROUNDS
 from .score import score_block
 from ..topology.locality import gang_state_update, gang_topology_term
-from ..utils.compile_cache import aot_call
-from ..utils.tracing import span
 
-__all__ = ["assign_cycle", "assign_cycle_epochs", "split_device_arrays", "INT32_MAX"]
+__all__ = ["assign_cycle", "split_device_arrays", "INT32_MAX"]
 
 # Pod-side keys the choose step consumes (sliced per block); the rest of the
 # pod state (assigned, active bookkeeping) never enters the score math.
@@ -212,10 +210,10 @@ def _choose_block(avail, nodes, weights, blk, pallas_pack=None, round_masks=None
 # shape: (use_pallas: bool, pods: dict, nodes: dict, topology: bool) -> bool
 def uses_kernel(use_pallas: bool, pods: dict, nodes: dict, topology: bool) -> bool:
     """Whether a cycle's choose step runs the fused Pallas kernel — the one
-    place the decision is made (bench.py reports it from here too).  The
-    kernel has no gang-locality operand yet, so topology cycles run the jnp
-    expression tree (bit-identical to native by construction — the term is
-    the same xp tree on both backends); otherwise the widths must fit it."""
+    place the decision is made.  The kernel has no gang-locality operand
+    yet, so topology cycles run the jnp expression tree (bit-identical to
+    native by construction — the term is the same xp tree on both
+    backends); otherwise the widths must fit it."""
     if not use_pallas or topology:
         return False
     from .pallas_choose import pallas_kernel_supported
@@ -304,10 +302,9 @@ _MIN_EPOCH_SIZE = 256
 
 # shape: (target: int, block: int) -> int
 def _chain_size(target: int, block: int) -> int:
-    """Align one shrinking-chain size — THE single rule for both drivers
-    (assign_cycle's static in-jit chain and assign_cycle_epochs' host-driven
-    halving): block multiples while above ``block`` (the blockwise choose
-    requires it), floored at _MIN_EPOCH_SIZE."""
+    """Align one size of assign_cycle's static in-jit shrinking chain:
+    block multiples while above ``block`` (the blockwise choose requires
+    it), floored at _MIN_EPOCH_SIZE."""
     if target > block:
         target = ((target + block - 1) // block) * block
     return max(_MIN_EPOCH_SIZE, target)
@@ -331,10 +328,8 @@ def _compact(ps):
 
 # shape: (pods: dict, block: int) -> ([P] i64, dict)
 def _prepare_pods(pods, block: int):
-    """Shared cycle setup — permute to priority order, pad to a block
-    multiple, init the auction bookkeeping, compact actives to the front.
-    ONE implementation for assign_cycle and the epoch driver: the two are
-    interchangeable by construction, so their setup must be too.
+    """Cycle setup — permute to priority order, pad to a block multiple,
+    init the auction bookkeeping, compact actives to the front.
 
     Priority order (priority desc, FIFO index asc); stable sort keeps FIFO.
     The permutation happens BEFORE any block padding: rank positions feed
@@ -362,8 +357,8 @@ def _prepare_pods(pods, block: int):
 #   pallas_interpret: bool, cmeta: dict, soft_spread: bool, soft_pa: bool,
 #   hard_pa: bool, tmeta: dict) -> fn
 def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta, soft_spread, soft_pa=False, hard_pa=True, tmeta=None):
-    """One auction round as a while_loop body (shared by the monolithic
-    assign_cycle and the size-shrinking epoch driver).
+    """One auction round as a while_loop body (every stage of
+    assign_cycle's size chain runs it).
 
     ``tmeta`` (topology/locality.TopologySet.meta_arrays) switches on the
     rank-aware gang co-placement term: each round derives the per-(gang,
@@ -440,8 +435,8 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
             # NO term progressed cannot open anyone's nodes (AA masks only
             # grow, capacity only shrinks), so the hopeless stragglers that
             # round 4's any-pod-placed rule pinned through the whole
-            # flagship tail (diag_constrained_tail: ~1.3k pods blocking the
-            # size chain) drain as soon as PA progress stops.
+            # flagship tail (measured: ~1.3k pods blocking the size chain)
+            # drain as soon as PA progress stops.
             new_match = (ps["pod_pa_matched"] * accepted[:, None].astype(jnp.float32)).sum(axis=0) > 0  # [Ta]
             pa_hope = (ps["pod_pa_declares"].sum(axis=1) > 0) & new_match.any()
             ps["active"] = ps["active"] | (was_active & ~has & pa_hope)
@@ -499,11 +494,9 @@ def assign_cycle(
     count fits it.  Compaction keeps actives in a prefix, so each stage
     transition folds the finished rows' results into full-size rank-space
     buffers and takes a static prefix slice — all on device, zero host
-    syncs.  This is the epoch driver's halving idea without its per-epoch
-    jit-boundary relayout and host-sync costs; results are bit-identical to
-    a single full-size loop because dropped rows are exactly the inactive
-    ones and padding rows never influence a round (sentinel cells,
-    rank-keyed jitter).
+    syncs.  Results are bit-identical to a single full-size loop because
+    dropped rows are exactly the inactive ones and padding rows never
+    influence a round (sentinel cells, rank-keyed jitter).
     """
     p_out = pods["pod_req"].shape[0]
     perm, ps = _prepare_pods(pods, block)
@@ -514,15 +507,15 @@ def assign_cycle(
         # Round-carried conflict state (spread water line, per-cell counts,
         # PA bootstrap flags) derived once at cycle start and updated
         # incrementally by constraint_commit inside the round body.
-        cstate = {**augment_round_state(jnp, cstate, cmeta, hard_pa=hard_pa), "stall": jnp.int32(0)}
+        cstate = {**augment_round_state(jnp, cstate, cmeta), "stall": jnp.int32(0)}
         ps["deferred"] = jnp.zeros((p,), bool)
 
     body = _make_round_body(
         nodes, weights, block, use_pallas, pallas_interpret, cmeta, soft_spread, soft_pa, hard_pa, tmeta
     )
 
-    # Static size chain: p, p/4, p/16, … — ONE alignment/floor rule shared
-    # with the epoch driver (_chain_size).  A stage is only appended when it
+    # Static size chain: p, p/4, p/16, … — aligned and floored by
+    # _chain_size.  A stage is only appended when it
     # at least halves the previous one: a near-no-op tail stage (e.g. 300 →
     # 256) would pay a full extra while_loop + compiled round-body variant
     # for negligible savings.
@@ -600,167 +593,3 @@ def assign_cycle(
 # zero rounds may still unstick; after STALL_ROUNDS identical-state rounds
 # the stragglers requeue to the next cycle instead (reference main.rs:122-125
 # semantics — a retry later, never a crash or a spin).
-
-
-# shape: (nodes: dict, pods: dict, block: int) -> ([P] i64, [N, R] i32, dict, scalar i32)
-@partial(jax.jit, static_argnames=("block",))
-def _epoch_prelude(nodes, pods, block: int):
-    """Jitted wrapper of the shared cycle setup, returning the state the
-    epoch loop drives (plus the permutation for the final unpermute)."""
-    perm, ps = _prepare_pods(pods, block)
-    return perm, nodes["node_avail"], ps, ps["active"].sum(dtype=jnp.int32)
-
-
-# shape: (nodes: dict, ps: dict, avail: [N, R] i32, n_active: scalar i32,
-#   rounds: scalar i32, cst: dict, weights: [W] f32, cmeta: dict,
-#   tmeta: dict, tst: dict) -> any
-@partial(jax.jit, static_argnames=("max_rounds", "block", "use_pallas", "pallas_interpret", "soft_spread", "soft_pa", "hard_pa", "floor"))
-def _assign_epoch(
-    nodes, ps, avail, n_active, rounds, cst, weights, cmeta,
-    max_rounds: int, block: int, use_pallas: bool, pallas_interpret: bool, soft_spread: bool, soft_pa: bool, hard_pa: bool, floor: bool,
-    tmeta=None, tst=None,
-):
-    """Run auction rounds until done — or, when not at the ``floor`` size,
-    until the active count falls to half the (static) pod-array size, so the
-    host driver can halve the arrays and re-enter at a cheaper size.
-
-    ``cmeta`` is a traced pytree operand; its None-vs-dict structure is part
-    of the jit cache key, which is what lets the body builder branch on it
-    at trace time (same contract as assign_cycle)."""
-    p = ps["pod_req"].shape[0]
-    body = _make_round_body(
-        nodes, weights, block, use_pallas, pallas_interpret, cmeta, soft_spread, soft_pa, hard_pa, tmeta
-    )
-
-    def cond(state):
-        _, _, n_active, rounds, cst, _tst = state
-        go = (rounds < max_rounds) & (n_active > 0)
-        if cmeta is not None:
-            go = go & (cst["stall"] < STALL_ROUNDS)
-        if not floor:
-            go = go & (2 * n_active > p)
-        return go
-
-    return lax.while_loop(cond, body, (avail, ps, n_active, rounds, cst, tst))
-
-
-# shape: (nodes: dict, pods: dict, weights: [W] f32, max_rounds: int,
-#   block: int, use_pallas: bool, pallas_interpret: bool, cmeta: dict,
-#   cstate: dict, soft_spread: bool, soft_pa: bool, hard_pa: bool,
-#   tmeta: dict, tstate: dict)
-#   -> ([P] i32, scalar i32, [N, R] i32, [P] i32, [P] i32)
-# hotpath: epochs-driver
-def assign_cycle_epochs(
-    nodes: dict,
-    pods: dict,
-    weights,
-    max_rounds: int = 32,
-    block: int = 4096,
-    use_pallas: bool = False,
-    pallas_interpret: bool = False,
-    cmeta: dict | None = None,
-    cstate: dict | None = None,
-    soft_spread: bool = False,
-    soft_pa: bool = False,
-    hard_pa: bool = True,
-    tmeta: dict | None = None,
-    tstate: dict | None = None,
-):
-    """assign_cycle with host-driven SIZE SHRINKING — the backend's driver.
-
-    Identical round-by-round math to :func:`assign_cycle` (same body fn),
-    but the pod arrays are re-sliced to half along a fixed halving chain
-    whenever the active count drops below half the current size: the accept
-    phase's per-round sort/scan/scatter cost then tracks the live pod count
-    instead of staying O(P_padded · log P) for all ~32 rounds.  Compaction
-    keeps actives in a prefix, so slicing drops only finished rows (their
-    results are folded into rank-space buffers first).  Each size on the
-    chain compiles once and is cached by jit; one host sync per epoch
-    (≤ log2(P/block) + 1 epochs).
-
-    NOT jittable (host control flow) — jittable contexts (dryrun, graft
-    entry) use :func:`assign_cycle`.
-    """
-    p_out = pods["pod_req"].shape[0]
-    perm, avail, ps, n_active_dev = aot_call(_epoch_prelude, {"block": block}, nodes, pods)
-    p_pad = ps["pod_req"].shape[0]
-    # Enter the loop on the static upper bound instead of blocking on the
-    # prelude's device count (an XFER finding: a whole extra device
-    # round-trip per cycle before any epoch had even dispatched).  The true
-    # active count rides home in epoch 0's single per-epoch fetch below; if
-    # it is 0 the epoch's while_loop exits without running a round and the
-    # results are identical.
-    n_active = p_pad
-    rounds = jnp.int32(0)
-    if cmeta is not None:
-        from .constraints import augment_round_state
-
-        # Same round-carried conflict state as assign_cycle, derived once
-        # (eagerly — the carry structure must be stable across epochs).
-        cst = {**augment_round_state(jnp, cstate, cmeta, hard_pa=hard_pa), "stall": jnp.int32(0)}
-        ps = {**ps, "deferred": jnp.zeros((p_pad,), bool)}
-    else:
-        cst = cstate
-    tst = tstate
-    assigned_rank = jnp.full((p_pad,), -1, jnp.int32)
-    acc_round_rank = jnp.full((p_pad,), -1, jnp.int32)
-
-    p_cur = p_pad
-    rounds_i = 0
-    epoch_i = 0
-    while rounds_i < max_rounds and n_active > 0:
-        floor = p_cur <= _MIN_EPOCH_SIZE
-        # Profiler attribution (utils/profiler.py): ``dispatch`` is the
-        # Python/trace cost of launching the epoch (the jit call returns
-        # before the device finishes — async dispatch), ``host-sync`` is the
-        # ONE per-epoch blocking fetch where the device execute + transfer
-        # time actually lands.  Both are host-side spans OUTSIDE the jit
-        # boundary (JAXP-clean); together with the jax.monitoring compile
-        # listener they decompose "solve" into compile / dispatch /
-        # device-execute+sync.
-        with span(f"epoch[{epoch_i}]"):
-            with span("dispatch"):
-                avail, ps, n_active_dev, rounds, cst, tst = aot_call(
-                    _assign_epoch,
-                    {
-                        "max_rounds": max_rounds, "block": block, "use_pallas": use_pallas,
-                        "pallas_interpret": pallas_interpret, "soft_spread": soft_spread, "soft_pa": soft_pa,
-                        "hard_pa": hard_pa, "floor": floor,
-                    },
-                    nodes, ps, avail, n_active_dev, rounds, cst, weights, cmeta, tmeta=tmeta, tst=tst,
-                )
-            # ONE host sync per epoch: n_active, rounds, and the stall
-            # counter ride home in a single fetch.
-            with span("host-sync"):
-                if cmeta is not None:
-                    trio = jnp.stack([n_active_dev, rounds, cst["stall"]])
-                    n_active, rounds_i, stall_i = (int(v) for v in trio)
-                else:
-                    duo = jnp.stack([n_active_dev, rounds])
-                    n_active, rounds_i = (int(v) for v in duo)
-                    stall_i = 0
-        epoch_i += 1
-        if stall_i >= STALL_ROUNDS:
-            break
-        if floor:
-            break
-        # Halving chain (alignment rule shared with assign_cycle's static
-        # in-jit chain: _chain_size), so late rounds touch hundreds of rows,
-        # not a full block.
-        new_size = p_cur
-        while new_size > _MIN_EPOCH_SIZE and n_active * 2 <= new_size:
-            new_size = _chain_size(new_size // 2, block)
-        if new_size < p_cur:
-            # Fold the rows about to be dropped (all finished — actives sit
-            # in the compacted prefix) into the rank-space result buffers.
-            assigned_rank = assigned_rank.at[ps["ranks"]].set(ps["assigned"])
-            acc_round_rank = acc_round_rank.at[ps["ranks"]].set(ps["acc_round"])
-            ps = {k: v[:new_size] for k, v in ps.items()}
-            p_cur = new_size
-
-    assigned_rank = assigned_rank.at[ps["ranks"]].set(ps["assigned"])
-    acc_round_rank = acc_round_rank.at[ps["ranks"]].set(ps["acc_round"])
-    out = jnp.full((p_out,), -1, jnp.int32).at[perm].set(assigned_rank[:p_out])
-    acc_round = jnp.full((p_out,), -1, jnp.int32).at[perm].set(acc_round_rank[:p_out])
-    rank_of = jnp.zeros((p_out,), jnp.int32).at[perm].set(jnp.arange(p_out, dtype=jnp.int32))
-    return out, rounds, avail, acc_round, rank_of

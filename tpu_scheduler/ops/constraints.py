@@ -772,19 +772,11 @@ def round_blocked_masks(
     if hard_pa:
         pa_m_node = _clip01(xp, state["pa_dom_m"] @ ndc_t + state["pa_node_m"])
         pa_unmatched_node = 1.0 - pa_m_node
-        # Round-carried bootstrap flags when the auction threads them
-        # (augment_round_state / constraint_commit); recompute otherwise.
-        pa_inactive = state.get("pa_inactive")
-        if pa_inactive is None:
-            pa_inactive = ((state["pa_dom_m"].sum(axis=1) + state["pa_node_m"].sum(axis=1)) == 0).astype(xp.float32)
+        # Round-carried bootstrap flags (augment_round_state / constraint_commit).
+        pa_inactive = state["pa_inactive"]
     uses = meta["sp_uses_dom"]
     counts = state["sp_counts"]
-    # Round-carried water line when present — bitwise what this recompute
-    # yields (counts are exact integers), just not re-reduced every round.
-    lo = state.get("sp_lo")
-    if lo is None:
-        lo = xp.min(xp.where(uses > 0, counts, RANK_INF), axis=1)
-        lo = xp.where(lo >= RANK_INF, 0.0, lo)
+    lo = state["sp_lo"]  # round-carried water line (augment_round_state)
     # Choose-time slack of CASCADE levels: the within-round admission filter
     # (constraint_filter) can raise the water line by up to CASCADE levels,
     # so domains within that reach are offered to declarers — otherwise the
@@ -1017,8 +1009,8 @@ def _null_span(name):
     return _NULL_SPAN_CTX
 
 
-# shape: (state: dict, meta: dict, hard_pa: bool) -> dict
-def augment_round_state(xp, state: dict, meta: dict, hard_pa: bool = True) -> dict:
+# shape: (state: dict, meta: dict) -> dict
+def augment_round_state(xp, state: dict, meta: dict) -> dict:
     """Derive the ROUND-CARRIED conflict-state entries from a cycle-start
     constraint state: ``sp_cell`` ([S,D] per-cell counts masked to used
     domains), ``sp_lo`` ([S] spread water line) and ``pa_inactive`` ([Ta]
@@ -1026,10 +1018,9 @@ def augment_round_state(xp, state: dict, meta: dict, hard_pa: bool = True) -> di
     its while-loop carry and :func:`constraint_commit` updates them
     INCREMENTALLY from each round's commits, so neither the choose-mask
     build nor the conflict filter re-derives them from the accumulated
-    domain history every round.  Values are bitwise what the per-round
-    recompute produced (counts are exact small-integer f32), so carried and
-    recomputed cycles place identically — the fallback recompute survives in
-    the consumers for legacy callers handing in a bare state dict."""
+    domain history every round.  The consumers (round_blocked_masks,
+    constraint_filter, constraint_commit) read these keys unconditionally,
+    so every caller augments its cycle-start ``state_arrays()`` here."""
     uses = meta["sp_uses_dom"]
     sp_cell = state["sp_counts"] * uses
     lo = xp.min(xp.where(uses > 0, sp_cell, RANK_INF), axis=1)
@@ -1101,9 +1092,7 @@ def constraint_filter(
     uses = meta["term_uses_dom"]  # [T, D]
     uses_sp = meta["sp_uses_dom"]  # [S, D]
     t = uses.shape[0]
-    sp0 = state.get("sp_cell")
-    if sp0 is None:  # legacy caller without the round-carried state
-        sp0 = state["sp_counts"] * uses_sp
+    sp0 = state["sp_cell"]
     # ONE fused gather matmul for every per-pod cell lookup: AA coarse-key
     # flags + coarse cell ids, spread key flags + own-cell round-start
     # counts ride a single banded [A,D] @ [D, 2T+2S] dispatch instead of
@@ -1182,11 +1171,7 @@ def constraint_filter(
     # POST-AA keep set, a sequential dependency.
     if hard_pa:
         with sp_span("pa"):
-            pa_inactive_f = state.get("pa_inactive")
-            if pa_inactive_f is None:  # legacy caller without the carry
-                pa_inactive_f = (
-                    (state["pa_dom_m"].sum(axis=1) + state["pa_node_m"].sum(axis=1)) == 0
-                ).astype(xp.float32)
+            pa_inactive_f = state["pa_inactive"]
             keep_pa_f = keep.astype(xp.float32)
             pa_m_acc = pw["pod_pa_matched"] * keep_pa_f[:, None]  # [A, Ta]
             min_match_rank = xp.min(xp.where(pa_m_acc > 0, rank_f[:, None], RANK_INF), axis=0)  # [Ta]
@@ -1211,8 +1196,8 @@ def constraint_filter(
     # workload's two skew levels per app) then mutually inflated each
     # other's minimum cells every round, pinning every quota at zero and
     # serializing the tail to ~1 accept/constraint/round (the measured
-    # 64-round cap: scripts/diag_round_kills.py printed "quota sum=0, open
-    # cells=0" for all eight fixpoint iterations).  The rank prefix breaks
+    # 64-round cap: a diagnostic run printed "quota sum=0, open cells=0"
+    # for all eight fixpoint iterations).  The rank prefix breaks
     # the deadlock structurally: the lowest-rank candidate of an open cell
     # always admits.
     with sp_span("spread"):
@@ -1226,21 +1211,14 @@ def constraint_filter(
         cand_m = keep_f[:, None] * matched * in_cell  # [A, S]
         decl_cell = keep_f[:, None] * declares * in_cell  # declarers on keyed nodes
 
-        lo0 = state.get("sp_lo")
-        if lo0 is None:  # legacy caller without the round-carried state
-            lo0 = xp.min(xp.where(uses_sp > 0, sp0, RANK_INF), axis=1)
-            lo0 = xp.where(lo0 >= RANK_INF, 0.0, lo0)  # [S] round-start water line
+        lo0 = state["sp_lo"]  # [S] round-start water line
 
         # Domain-axis projection: the cell passes only ever touch domains a
         # spread constraint references, so they run on the [D, Ds] pack-time
         # selection (sp_dom_sel) — dropped columns were identically zero in
         # every product and RANK_INF in every min, so admissions are bitwise
         # unchanged while a zone-keyed cluster's passes shrink ~D/Ds-fold.
-        sel = meta.get("sp_dom_sel")
-        if sel is None:  # legacy caller without the selection tensor
-            nd_sp, uses_spc, sp0c = nd, uses_sp, sp0
-        else:
-            nd_sp, uses_spc, sp0c = _project_spread_domains(xp, nd, uses_sp, sp0, sel)
+        nd_sp, uses_spc, sp0c = _project_spread_domains(xp, nd, uses_sp, sp0, meta["sp_dom_sel"])
         # ONE spread formulation for every size: the [A,S,Ds] cell passes
         # run one-shot when they fit the byte budget and pod-axis CHUNKED
         # otherwise (exact small-integer sums — bitwise identical either
@@ -1354,19 +1332,15 @@ def constraint_commit(
     # tensors); ``pa_inactive`` flips per-term the moment any accepted match
     # commits (exactly when the pa_dom_m/pa_node_m sums leave zero: a
     # matched accepted pod lands in its domain when the node carries the
-    # key, in its node row otherwise — either way the sum grows).  Dict
-    # membership gates the update so legacy callers handing in a bare
-    # state_arrays() dict keep the old contract.
-    if "sp_cell" in state:
-        uses_sp = meta["sp_uses_dom"]
-        sp_cell = sp_counts * uses_sp
-        lo = xp.min(xp.where(uses_sp > 0, sp_cell, RANK_INF), axis=1)
-        out["sp_cell"] = sp_cell
-        out["sp_lo"] = xp.where(lo >= RANK_INF, 0.0, lo)
-    if "pa_inactive" in state:
-        if hard_pa:
-            newly_matched = (matc_pa.sum(axis=0) > 0).astype(xp.float32)  # [Ta]
-            out["pa_inactive"] = state["pa_inactive"] * (1.0 - newly_matched)
-        else:
-            out["pa_inactive"] = state["pa_inactive"]
+    # key, in its node row otherwise — either way the sum grows).
+    uses_sp = meta["sp_uses_dom"]
+    sp_cell = sp_counts * uses_sp
+    lo = xp.min(xp.where(uses_sp > 0, sp_cell, RANK_INF), axis=1)
+    out["sp_cell"] = sp_cell
+    out["sp_lo"] = xp.where(lo >= RANK_INF, 0.0, lo)
+    if hard_pa:
+        newly_matched = (matc_pa.sum(axis=0) > 0).astype(xp.float32)  # [Ta]
+        out["pa_inactive"] = state["pa_inactive"] * (1.0 - newly_matched)
+    else:
+        out["pa_inactive"] = state["pa_inactive"]
     return out

@@ -117,7 +117,7 @@ def score_block(
         # bucket are treated as exact ties and ordered by the hash alone, so
         # claimants spread UNIFORMLY across the whole near-tied band instead
         # of clustering around its additive-jitter-weighted top.  Measured
-        # motivation (round 5, scripts/diag_round_kills.py): with additive
+        # motivation (round 5, a per-round kill diagnostic): with additive
         # jitter the flagship constrained tail's ~16k claimants chose only
         # ~11 distinct nodes per term — a few leader nodes sat just above
         # the ±32-point band and the capacity prefix killed 15k claimants a

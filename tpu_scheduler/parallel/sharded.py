@@ -207,7 +207,7 @@ def _build_shard_map(
             cst0 = {k: named[k] for k in CONSTRAINT_KEYS[_N_PODKEYS + _N_METAKEYS :]}
             # Round-carried conflict state, replicated like the rest of the
             # constraint carry (ops/assign.py twin).
-            cst0 = augment_round_state(jnp, cst0, cmeta, hard_pa=hard_pa)
+            cst0 = augment_round_state(jnp, cst0, cmeta)
             cst0["stall"] = jnp.int32(0)
             cst0["deferred"] = jnp.zeros((p_tot,), bool)  # global rows the filter deferred: they claim last
             # This device's dp rows of the (replicated) pod bitmaps.

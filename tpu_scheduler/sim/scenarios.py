@@ -186,7 +186,7 @@ _register(
 _register(
     Scenario(
         name="arrival-rate-sweep",
-        description="The latency bench's scenario family: steady-state's cluster shape at a parameterized Poisson rate (arrival_rate_variant), pass-gated on the time-to-bind waterfall summing to TTB — bench.py latency_row sweeps the rate to put the TTB-vs-load curve on the record",
+        description="The latency bench's scenario family: steady-state's cluster shape at a parameterized Poisson rate (arrival_rate_variant), pass-gated on the time-to-bind waterfall summing to TTB — sweeping the rate puts the TTB-vs-load curve on the record",
         duration=45.0,
         workload=WorkloadSpec(
             initial_nodes=60,
@@ -206,7 +206,7 @@ _register(
 # shape: (rate: obj) -> obj
 def arrival_rate_variant(rate: float) -> Scenario:
     """The ``arrival-rate-sweep`` family member at a given Poisson rate —
-    the parameterization bench.py's latency_row sweeps.  Variants are NOT
+    the parameterization a rate sweep walks.  Variants are NOT
     registered (the registry stays the closed, README-documented set); the
     harness accepts Scenario objects directly."""
     from dataclasses import replace

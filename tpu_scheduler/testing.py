@@ -1,6 +1,6 @@
 """Synthetic cluster generation — the "kind-style synthetic cluster" of
-BASELINE.json config 3, used by tests, the fake API server fixtures, and
-bench.py.  Deterministic via an explicit seed.
+BASELINE.json config 3, used by tests, the fake API server fixtures and
+chip_smoke.py.  Deterministic via an explicit seed.
 """
 
 from __future__ import annotations

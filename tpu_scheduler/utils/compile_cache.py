@@ -3,7 +3,7 @@
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
 module sets nothing.  Otherwise the cache lives at one fixed path inside the
 checkout: the path is part of the cache's key, so a directory that moves
-never hits.  Opt-in from entry points (bench.py, cli.py, chip_smoke.py)
+never hits.  Opt-in from entry points (cli.py, chip_smoke.py)
 rather than at import so library users keep control of jax config.
 
 :func:`aot_call` compiles a jitted function ahead of time on a signature

@@ -1,7 +1,7 @@
 """Scheduling flight recorder — bounded per-pod decision timelines plus a
 ring buffer of recent cycles, the in-process answer to "why is this pod
 Pending?" and "what did cycle N spend its time on?" without re-running
-bench.py (VERDICT round 5: classify and surface unschedulable pods as a
+a benchmark (VERDICT round 5: classify and surface unschedulable pods as a
 product feature, not a bench field).
 
 Every verdict the controller reaches about a pod — seen-pending, packed,

@@ -22,9 +22,7 @@ Five pieces:
   • **Compile/execute split** — ``install_jax_profile_hooks`` registers
     ``jax.monitoring`` listeners so XLA compiles land in the active trace as
     ``compile`` spans (and in global counters); ``record_transfer`` counts
-    host→device bytes at the TpuBackend's device_put seam.  Together with
-    the epoch driver's ``dispatch``/``host-sync`` spans, "solve time"
-    decomposes into compile / device-execute / host-sync / Python.
+    host→device bytes at the TpuBackend's device_put seam.
   • **Collector pauses** — ``install_gc_listener`` times every garbage
     collection (``gc.callbacks``) into a bounded record read through
     ``gc_pauses``; the controller folds it into
@@ -79,7 +77,7 @@ SPAN_CATALOGUE = (
     "noexecute",   # NoExecute taint eviction scan
     "queue",       # eligibility filter, backoff prune, cycle-snapshot rebuild, gang census
     "pack",        # snapshot -> device tensors (full or incremental)
-    "solve",       # backend auction (rounds/epochs nest under it)
+    "solve",       # backend auction (rounds nest under it)
     "constrained", # host sequential phase (untensorizable constraint fallback)
     "mopup",       # stall-residue sequential completeness pass
     "bind",        # binding POSTs / deferred-bind bookkeeping
@@ -110,9 +108,6 @@ SPAN_CATALOGUE = (
     "migrate",     # rebalance sub-span: breaker-gated unbinds + cordons
     "pump",        # autoscale sub-span: provider lifecycle pump (joins, reclaims, kills)
     "scale",       # autoscale sub-span: scale-up requests / scale-down drains
-    "epoch",       # one epoch of the host-driven size-shrinking driver
-    "dispatch",    # epoch dispatch (async jit call; Python + trace time)
-    "host-sync",   # the one per-epoch device fetch (device execute + transfer)
     "compile",     # XLA compile time observed via jax.monitoring
 )
 

@@ -85,8 +85,8 @@ def profiler_annotation():
 
 
 def base_name(segment: str) -> str:
-    """A path segment's catalogue name: indexed spans (``round[03]``,
-    ``epoch[1]``) aggregate under their base (``round``, ``epoch``)."""
+    """A path segment's catalogue name: indexed spans (``round[03]``)
+    aggregate under their base (``round``)."""
     i = segment.find("[")
     return segment if i < 0 else segment[:i]
 
