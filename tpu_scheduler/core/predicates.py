@@ -16,8 +16,10 @@ import enum
 from itertools import chain
 from typing import Callable, Iterable, Sequence
 
+import numpy as np
+
 from ..api.objects import LabelSelectorRequirement, Node, Pod, full_name, total_pod_resources
-from .snapshot import ClusterSnapshot, PlacedOverlay, node_net_available
+from .snapshot import ClusterSnapshot, NodeTable, PlacedOverlay, node_net_available
 
 __all__ = [
     "InvalidNodeReason",
@@ -47,6 +49,7 @@ __all__ = [
     "dominant_reason",
     "PREDICATE_CHAIN",
     "NODE_LOCAL_PREDICATES",
+    "INTER_POD_REASONS",
 ]
 
 
@@ -554,6 +557,79 @@ _CHECKER_OF: dict[Callable, Callable[[Pod, ClusterSnapshot], Callable[[Node], bo
     topology_spread_ok: make_spread_checker,
 }
 
+# The reasons the inter-pod checkers charge: a breakdown's nodes that pass
+# every node-local predicate are the feasible ones plus these.
+INTER_POD_REASONS: tuple[str, ...] = tuple(r.value for r, pred in PREDICATE_CHAIN if pred in _CHECKER_OF)
+
+
+# --- the chain's node-local predicates over the node axis -------------------
+# Each takes (pod, table) and returns the bool verdict of every node of the
+# table, or None where the pod passes every node (the vacuous case).  They
+# are the scalar predicates above, node for node: the label and taint masks
+# evaluate the scalar code once per distinct value or taint tuple and
+# broadcast it by id.
+
+
+def _lookup(lut: list[bool], ids: np.ndarray) -> np.ndarray:
+    """Per-node verdicts from per-id ones; ``lut[-1]`` is the verdict of id -1."""
+    return np.array(lut, dtype=bool)[ids]
+
+
+def _resources_mask(pod: Pod, table: NodeTable) -> np.ndarray:
+    req = total_pod_resources(pod)  # PodResources.fits_in, node for node
+    ok = (req.cpu <= table.cpu) & (req.memory <= table.memory)
+    for k, v in (req.extended or {}).items():
+        avail = table.extended.get(k)
+        ok &= v <= avail if avail is not None else v <= 0
+    return ok
+
+
+def _node_selector_mask(pod: Pod, table: NodeTable) -> np.ndarray | None:
+    if pod.spec is None or not pod.spec.node_selector:
+        return None
+    ok = np.ones(len(table.cpu), dtype=bool)
+    for k, v in pod.spec.node_selector.items():
+        ids, vocab = table.label_ids(k)
+        ok &= _lookup([u == v for u in vocab] + [False], ids)  # a node lacking the key, or any label, fails
+    return ok
+
+
+def _node_affinity_mask(pod: Pod, table: NodeTable) -> np.ndarray | None:
+    terms = (pod.spec.node_affinity or []) if pod.spec is not None else []
+    if not terms:
+        return None
+    ok = np.zeros(len(table.cpu), dtype=bool)
+    for term in terms:
+        if not term.match_expressions:
+            continue  # matches nothing
+        held = np.ones(len(table.cpu), dtype=bool)
+        for r in term.match_expressions:
+            ids, vocab = table.label_ids(r.key)
+            lut = [_node_expression_matches(r, {r.key: u}) for u in vocab]
+            held &= _lookup(lut + [_node_expression_matches(r, {})], ids)
+        ok |= held
+    return ok
+
+
+def _schedulable_mask(pod: Pod, table: NodeTable) -> np.ndarray:
+    return ~table.cordoned
+
+
+def _taints_mask(pod: Pod, table: NodeTable) -> np.ndarray:
+    return _lookup([taints_tolerated(pod, node) for node in table.taint_nodes], table.taint_ids)
+
+
+_MASK_OF: dict[Callable, Callable[[Pod, NodeTable], np.ndarray | None]] = {
+    pod_fits_resources: _resources_mask,
+    node_selector_matches: _node_selector_mask,
+    node_affinity_matches: _node_affinity_mask,
+    node_schedulable: _schedulable_mask,
+    taints_tolerated: _taints_mask,
+}
+# The chain's leading predicates that have a mask: the breakdown masks them
+# and walks the rest node by node.
+_N_MASKED = next((i for i, (_, pred) in enumerate(PREDICATE_CHAIN) if pred not in _MASK_OF), len(PREDICATE_CHAIN))
+
 
 # shape: (pod: obj, node: obj, snapshot: obj) -> obj
 def check_node_validity(pod: Pod, node: Node, snapshot: ClusterSnapshot) -> InvalidNodeReason | None:
@@ -574,18 +650,30 @@ def unschedulable_reason_counts(pod: Pod, snapshot: ClusterSnapshot) -> tuple[di
     is charged to the FIRST failing predicate in chain order.  Returns
     ``(counts-by-reason-value, feasible_nodes, nodes_total)`` — the payload
     of the flight recorder's "unschedulable" event and the /debug why-pending
-    route (utils/events.py, runtime/http_api.py).  O(nodes) host work per
-    pod: callers on the cycle path budget it (Scheduler.EXPLAIN_WORK).
+    route (utils/events.py, runtime/http_api.py).  O(nodes) work per pod:
+    callers on the cycle path budget it (Scheduler.EXPLAIN_WORK).
 
-    The same chain as :func:`check_node_validity`, node for node, except
-    that each inter-pod predicate's checker (anti-affinity, pod affinity,
-    hard spread) is built once for the pod, when the first node reaches it,
-    instead of once a node: a build walks every placed pod."""
-    chain_ = [(reason.value, pred, _CHECKER_OF.get(pred)) for reason, pred in PREDICATE_CHAIN]
-    checkers: dict[Callable, Callable[[Node], bool]] = {}
+    The same chain as :func:`check_node_validity`, node for node.  Its
+    node-local prefix (resources through taints) is charged as one mask per
+    predicate over the snapshot's node table; the nodes that pass it walk,
+    in snapshot order, the inter-pod checkers (anti-affinity, pod affinity,
+    hard spread), each built once for the pod when the first node reaches
+    it: a build walks every placed pod."""
+    table = snapshot.node_table()
+    alive = np.ones(len(snapshot.nodes), dtype=bool)
     counts: dict[str, int] = {}
+    for reason, pred in PREDICATE_CHAIN[:_N_MASKED]:
+        passed = _MASK_OF[pred](pod, table)
+        if passed is not None:
+            failed = int(np.count_nonzero(alive & ~passed))
+            if failed:
+                counts[reason.value] = failed
+            alive &= passed
+    chain_ = [(reason.value, pred, _CHECKER_OF.get(pred)) for reason, pred in PREDICATE_CHAIN[_N_MASKED:]]
+    checkers: dict[Callable, Callable[[Node], bool]] = {}
     feasible = 0
-    for node in snapshot.nodes:
+    for i in np.flatnonzero(alive).tolist():
+        node = snapshot.nodes[i]
         for reason, pred, make in chain_:
             if make is None:
                 ok = pred(pod, node, snapshot)

@@ -15,10 +15,12 @@ from dataclasses import dataclass, field
 from itertools import chain
 from typing import Iterable
 
+import numpy as np
+
 from ..api.objects import Node, Pod, PodResources, is_extended_resource, is_pod_bound, total_pod_resources
 from ..api.quantity import cpu_to_millis, memory_to_bytes
 
-__all__ = ["ClusterSnapshot", "PlacedOverlay", "node_allocatable", "node_net_available", "node_used_resources"]
+__all__ = ["ClusterSnapshot", "NodeTable", "PlacedOverlay", "node_allocatable", "node_net_available", "node_used_resources"]
 
 
 def node_allocatable(node: Node, snapshot: "ClusterSnapshot | None" = None) -> PodResources:
@@ -84,6 +86,9 @@ class ClusterSnapshot:
     _placed_by_label: dict | None = field(default=None, compare=False, repr=False)
     # Lazy per-key memo of the named topology domains (named_domains).
     _domains_by_key: dict[str, tuple[str, ...]] = field(default_factory=dict, compare=False, repr=False)
+    # Lazy column view of the nodes (node_table), built by the first
+    # why-pending breakdown a snapshot serves.
+    _node_table: "NodeTable | None" = field(default=None, compare=False, repr=False)
     # Lazy pending-pod memo (immutable snapshot, so one scan suffices): the
     # controller consults the pending list several times per cycle — at
     # flagship scale each uncached scan walks 200k+ pods.
@@ -151,6 +156,12 @@ class ClusterSnapshot:
             domains = self._domains_by_key[topology_key] = tuple(dict.fromkeys(v for v in labels if v is not None))
         return domains
 
+    def node_table(self) -> "NodeTable":
+        """The nodes as numpy columns, built once a snapshot, on first use."""
+        if self._node_table is None:
+            object.__setattr__(self, "_node_table", NodeTable(self))
+        return self._node_table
+
     def pods_on_node(self, node_name: str) -> list[Pod]:
         """Snapshot equivalent of the reference's live field-selector list
         ``spec.nodeName=<node>`` (``src/predicates.rs:22-26``)."""
@@ -168,6 +179,58 @@ class ClusterSnapshot:
                 self, "_pending", [p for p in self.pods if p.status.phase == "Pending" and not is_pod_bound(p)]
             )
         return self._pending
+
+
+class NodeTable:
+    """A snapshot's nodes as numpy columns, in node order, for predicates
+    charged over the whole node axis at once (the why-pending breakdown,
+    core/predicates.unschedulable_reason_counts).
+
+    ``cpu``, ``memory`` and ``extended[name]``: net-available resources
+    (node_net_available), 0 where a node lacks an extended resource; exact
+    Python-int semantics (numpy widens a column past int64 to objects).
+    ``label_ids(key)``: each node's value id under ``key`` (-1 where the key
+    is missing) and the vocabulary the ids index.  ``cordoned``:
+    ``spec.unschedulable``.
+    ``taint_ids``: each node's id among the distinct (key, value, effect)
+    tuples of its taints, and ``taint_nodes`` the first node of each, for a
+    per-pod verdict a tuple that the ids broadcast.
+    """
+
+    __slots__ = ("cpu", "memory", "extended", "cordoned", "taint_ids", "taint_nodes", "_labels")
+
+    def __init__(self, snapshot: ClusterSnapshot):
+        nodes = snapshot.nodes
+        net = [node_net_available(snapshot, node) for node in nodes]
+        self.cpu = np.array([r.cpu for r in net])
+        self.memory = np.array([r.memory for r in net])
+        names = dict.fromkeys(k for r in net if r.extended for k in r.extended)
+        self.extended = {k: np.array([r.extended.get(k, 0) if r.extended else 0 for r in net]) for k in names}
+        self.cordoned = np.array([node.spec is not None and bool(node.spec.unschedulable) for node in nodes], dtype=bool)
+        labels: dict[str, tuple[np.ndarray, dict]] = {}
+        taint_of: dict[tuple, int] = {}
+        self.taint_nodes: list[Node] = []
+        self.taint_ids = np.empty(len(nodes), dtype=np.int32)
+        for i, node in enumerate(nodes):
+            for k, v in (node.metadata.labels or {}).items():
+                col = labels.get(k)
+                if col is None:
+                    col = labels[k] = (np.full(len(nodes), -1, dtype=np.int32), {})
+                col[0][i] = col[1].setdefault(v, len(col[1]))
+            taints = tuple((t.key, t.value, t.effect) for t in ((node.spec.taints or []) if node.spec is not None else []))
+            tid = taint_of.get(taints)
+            if tid is None:
+                tid = taint_of[taints] = len(self.taint_nodes)
+                self.taint_nodes.append(node)
+            self.taint_ids[i] = tid
+        self._labels = {k: (ids, list(vocab)) for k, (ids, vocab) in labels.items()}
+
+    def label_ids(self, key: str) -> tuple[np.ndarray, list]:
+        """(value id per node, -1 where ``key`` is missing; the values)."""
+        col = self._labels.get(key)
+        if col is None:
+            col = self._labels[key] = (np.full(len(self.cpu), -1, dtype=np.int32), [])
+        return col
 
 
 class PlacedOverlay:

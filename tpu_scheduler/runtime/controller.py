@@ -41,6 +41,7 @@ from itertools import chain, groupby
 from ..api.objects import Node, ObjectReference, Pod, PodResources, PodSpec, full_name, is_pod_bound, total_pod_resources
 from ..backends.base import SchedulingBackend
 from ..core.predicates import (
+    INTER_POD_REASONS,
     NODE_LOCAL_PREDICATES,
     InvalidNodeReason,
     dominant_reason,
@@ -608,6 +609,8 @@ class Scheduler:
                 counts, feasible, total = unschedulable_reason_counts(pod, snap)
                 self._explain_s += time.perf_counter() - t0
                 self.metrics.inc("scheduler_explained_pods_total")
+                checked = feasible + sum(counts.get(r, 0) for r in INTER_POD_REASONS)
+                self.metrics.inc("scheduler_explain_checked_nodes_total", checked)
                 reason_value = dominant_reason(counts, feasible)
         self.metrics.inc("scheduler_unschedulable_total", labels={"reason": reason_value})
         self.recorder.record(
