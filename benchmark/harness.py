@@ -13,7 +13,11 @@ program is touched.
 Everything a cell needs is found by name:
 
 - ``BENCHMARK.json``: the cell, its configuration and traffic, its metrics;
-- the configuration's ``file``: nodes and standing pods;
+- the configuration's ``file``: nodes and standing pods, made by
+  ``benchmark/clusters/<generator>.py`` where the file names a
+  ``generator``, else by ``benchmark/cluster.py``; ``gangs_placed_when_they_fit``
+  true where the deployment guarantees that a gang that fits is not left
+  pending, so that the reference judges its pending gangs;
 - ``benchmark/traffic/<traffic>.json``: the mix, read by the loop it names;
 - ``benchmark/loops/<loop>.py``: ``prepare``, ``warm`` and ``measure``;
 - ``benchmark/metrics/<metric>.py``: ``read(run)``, a number or None.
@@ -262,11 +266,16 @@ def _strip_labels_constraints(pod):
     return replace(pod, metadata=replace(pod.metadata), spec=spec)
 
 
-def build_cluster(run: Run) -> list:
-    """Nodes and standing pods of the configuration, from the seed."""
+def build_cluster(run: Run, root: str = ROOT) -> list:
+    """Nodes and standing pods of the configuration, from the seed, by the
+    generator the configuration names (``benchmark/clusters/<generator>.py``),
+    or by ``benchmark/cluster.py`` where it names none."""
+    generator = cluster
+    if "generator" in run.config:
+        generator = load_module(os.path.join(root, "benchmark", "clusters", run.config["generator"] + ".py"))
     rng = random.Random(f"{run.seed}/cluster")
-    run.nodes = cluster.gen_nodes(rng, run.config["nodes"])
-    return cluster.gen_standing(rng, run.config["standing"], run.nodes)
+    run.nodes = generator.gen_nodes(rng, run.config["nodes"])
+    return generator.gen_standing(rng, run.config["standing"], run.nodes)
 
 
 def build_scheduler(run: Run, fault: str | None) -> None:
@@ -345,7 +354,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, t_process_s
 
     run = Run(workload, cell, config, traffic, seed, seconds, traced, t_process_start, devices[0].device_kind, drain_seconds)
     loop = load_module(os.path.join(root, "benchmark", "loops", traffic["loop"] + ".py"))
-    standing = build_cluster(run)
+    standing = build_cluster(run, root)
     loop.prepare(run)
     build_scheduler(run, fault)
     t0 = time.perf_counter()
@@ -381,8 +390,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, t_process_s
         }
 
     t_ref = time.perf_counter()
-    inter_pod = any(traffic["mix"].get(k) for k in ("anti_affinity_fraction", "pod_affinity_fraction", "spread_fraction"))
-    verdict = audit.replay(run.nodes, run.log, group_of(run.cycles), inter_pod)
+    verdict = audit.replay(run.nodes, run.log, group_of(run.cycles), bool(config.get("gangs_placed_when_they_fit")))
     run.ttb = time_to_bind(run, verdict.fitting)
     say(f"reference: {len(run.log)} accepted operations replayed in {time.perf_counter() - t_ref:.3f}s")
     for check, examples in verdict.examples.items():

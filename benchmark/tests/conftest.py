@@ -13,35 +13,45 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import pytest  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-# Per traffic mix: (node count, standing pods, wave pods).
-TINY = {"wave-plain": (200, 400, 1500), "wave-mixed": (200, 4000, 200)}
 
 
-def make_tiny_root(dest: str) -> str:
-    """A checkout of the benchmark with every configuration and traffic mix
-    cut to a size a CPU test can run in seconds."""
-    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), dest)
-    shutil.copytree(os.path.join(REPO, "benchmark"), os.path.join(dest, "benchmark"), ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    spec = json.load(open(os.path.join(dest, "BENCHMARK.json")))
-    # The constraint-heavy mix is no cell of the benchmark yet (PERF.md, Open
-    # questions); its tiny cell keeps the inter-pod reference under test.
-    spec["workloads"].append({"name": "wave-mixed-5k", "config": "envelope-5k", "traffic": "wave-mixed", "chips": 1, "why": "test"})
-    spec["configs"].append({"name": "envelope-5k", "source": "test", "file": "benchmark/configs/envelope-5k.json", "reduced": [], "why": "test"})
-    for m in spec["end_to_end"]:
-        if "workloads" in m and "wave-plain-100k" in m["workloads"]:
-            m["workloads"].append("wave-mixed-5k")
-    json.dump(spec, open(os.path.join(dest, "BENCHMARK.json"), "w"))
+def merge(base: dict, cut: dict) -> dict:
+    """``base`` with ``cut`` laid over it: objects merge key by key, any
+    other value of ``cut`` replaces the one in ``base``."""
+    out = dict(base)
+    for k, v in cut.items():
+        out[k] = merge(base[k], v) if isinstance(v, dict) and isinstance(base.get(k), dict) else v
+    return out
+
+
+def make_tiny_root(dest: str, src: str = REPO) -> str:
+    """A checkout of the benchmark in ``src`` with every cell cut to a size a
+    CPU test can run in seconds.  Each cell's cut is found by name,
+    ``benchmark/tests/tiny/<cell>.json``: ``{"config": {...}, "traffic":
+    {...}}``, merged into the cell's configuration and traffic files.  Cells
+    that share a file must cut it alike."""
+    shutil.copytree(os.path.join(src, "benchmark"), os.path.join(dest, "benchmark"), ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    with open(os.path.join(src, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(dest, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    written: dict = {}  # path -> (cell, what was written there)
     for cell in spec["workloads"]:
-        nodes, standing, load = TINY[cell["traffic"]]
-        conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
-        path = os.path.join(dest, conf["file"])
-        cfg = json.load(open(path))
-        cfg["nodes"]["count"], cfg["standing"]["count"] = nodes, standing
-        json.dump(cfg, open(path, "w"))
-        path = os.path.join(dest, "benchmark", "traffic", cell["traffic"] + ".json")
-        traffic = json.load(open(path))
-        traffic["wave_pods"], traffic["warm_waves"] = load, 1
-        json.dump(traffic, open(path, "w"))
+        path = os.path.join(src, "benchmark", "tests", "tiny", cell["name"] + ".json")
+        if not os.path.isfile(path):
+            raise FileNotFoundError(f"cell {cell['name']!r} has no tiny cut: add benchmark/tests/tiny/{cell['name']}.json")
+        with open(path) as f:
+            cut = json.load(f)
+        for part, rel in (("config", files[cell["config"]]), ("traffic", os.path.join("benchmark", "traffic", cell["traffic"] + ".json"))):
+            with open(os.path.join(src, rel)) as f:
+                data = merge(json.load(f), cut.get(part, {}))
+            out = os.path.join(dest, rel)
+            if out in written and written[out][1] != data:
+                raise ValueError(f"cells {written[out][0]!r} and {cell['name']!r} cut {rel} differently")
+            written[out] = (cell["name"], data)
+            with open(out, "w") as f:
+                json.dump(data, f)
     return dest
 
 

@@ -12,6 +12,7 @@ import pytest
 
 from benchmark import harness
 from benchmark.tests.conftest import REPO
+from benchmark.tests.test_faults import FAULTS
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -104,3 +105,87 @@ def test_tiny_run_is_correct_and_reports_its_metrics(tiny_root, workload):
     assert set(result["metrics"]) == want
     assert all(v["value"] > 0 for v in result["metrics"].values())
 
+
+
+FOUND_BY_NAME = os.path.join(REPO, "benchmark", "tests", "found_by_name")
+
+
+def _with_fleet_cell(src: str) -> str:
+    """A copy of the repo's benchmark in ``src`` with a cell of its own
+    cluster generator, loop, traffic, configuration and tiny cut added as new
+    files (``benchmark/tests/found_by_name``) and new entries alone."""
+    import shutil
+
+    shutil.copytree(os.path.join(REPO, "benchmark"), os.path.join(src, "benchmark"), ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), src)
+    for dirpath, _dirs, files in os.walk(FOUND_BY_NAME):
+        rel = os.path.relpath(dirpath, FOUND_BY_NAME)
+        for f in files:
+            assert not os.path.exists(os.path.join(REPO, "benchmark", rel, f)), "a new file, not an edit"
+            os.makedirs(os.path.join(src, "benchmark", rel), exist_ok=True)
+            shutil.copy(os.path.join(dirpath, f), os.path.join(src, "benchmark", rel, f))
+    with open(os.path.join(src, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "tpu-fleet", "source": "test", "file": "benchmark/configs/tpu-fleet.json", "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "jobset-fleet", "config": "tpu-fleet", "traffic": "jobset-waves", "chips": 1, "why": "test"})
+    next(m for m in spec["end_to_end"] if m["name"] == "pods_per_s")["workloads"].append("jobset-fleet")
+    with open(os.path.join(src, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return src
+
+
+@pytest.fixture(scope="module")
+def fleet_root(tmp_path_factory):
+    from benchmark.tests.conftest import make_tiny_root
+
+    src = _with_fleet_cell(str(tmp_path_factory.mktemp("fleet-src")))
+    return make_tiny_root(str(tmp_path_factory.mktemp("fleet-tiny")), src)
+
+
+@pytest.mark.parametrize("fault", [None, *FAULTS])
+def test_a_cell_with_its_own_cluster_loop_and_cut_is_found_by_name(fleet_root, fault):
+    """Slice node pools and standing JobSet jobs from the configuration's own
+    generator, exclusive-placement gangs from the traffic's own loop, the
+    size from the cell's own tiny cut: correct, and the control and every
+    fault are caught."""
+    spec = json.load(open(os.path.join(fleet_root, "BENCHMARK.json")))
+    _cell, config, traffic = harness.resolve(spec, "jobset-fleet", fleet_root)
+    assert config["nodes"]["slice_topologies"] == ["4x4", "4x4", "4x4", "4x8", "4x8"] and traffic["wave_jobs"] == 2
+    result = harness.run_cell("jobset-fleet", 2**31 + 29, 2.0, False, time.perf_counter(), fault=fault, require_tpu=False,
+                              root=fleet_root, drain_seconds=5)
+    if fault is None:
+        assert result["correct"], result["checks"]
+        assert result["attempted"] > 0 and set(result["metrics"]) == {"setup_s", "pods_per_s", "ttb_p99_s"}
+    else:
+        assert not result["correct"]
+        assert any(c["value"] > c["limit"] for c in result["checks"].values())
+
+
+def test_a_cell_without_a_tiny_cut_is_named(tmp_path):
+    from benchmark.tests.conftest import make_tiny_root
+
+    src = _with_fleet_cell(str(tmp_path / "src"))
+    os.remove(os.path.join(src, "benchmark", "tests", "tiny", "jobset-fleet.json"))
+    os.mkdir(tmp_path / "tiny")
+    with pytest.raises(FileNotFoundError, match="'jobset-fleet'"):
+        make_tiny_root(str(tmp_path / "tiny"), src)
+
+
+@pytest.mark.parametrize("config", ["northstar-100k", "envelope-5k"])
+def test_build_cluster_without_a_generator_is_the_benchmarks_own(config):
+    """A configuration that names no generator gets ``benchmark/cluster.py``'s
+    nodes and standing pods, drawn from the same seed in the same order."""
+    import random
+
+    from benchmark import cluster
+    from benchmark.tests.test_cluster import _strip
+
+    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    cfg = json.load(open(os.path.join(REPO, next(c["file"] for c in spec["configs"] if c["name"] == config))))
+    assert "generator" not in cfg
+    run = harness.Run("w", {}, cfg, {}, 2**31 + 7, 1.0, False, 0.0, "test", 1.0)
+    standing = harness.build_cluster(run)
+    rng = random.Random(f"{2**31 + 7}/cluster")
+    nodes = cluster.gen_nodes(rng, cfg["nodes"])
+    assert _strip(run.nodes) == _strip(nodes)
+    assert _strip(standing) == _strip(cluster.gen_standing(rng, cfg["standing"], nodes))

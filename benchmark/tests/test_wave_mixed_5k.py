@@ -13,13 +13,8 @@ import pytest
 
 from benchmark import roofline, roofline_constrained
 from benchmark.loops import wave_guarded
-from benchmark.tests import conftest
 from benchmark.tests.conftest import REPO
 from benchmark.tests.test_layer_readers import reader, synthetic_run
-
-# make_tiny_root sizes every cell by its traffic mix; this cell's mix is
-# wave-mixed's at wave-mixed's tiny size.
-conftest.TINY.setdefault("wave-mixed-5k", conftest.TINY["wave-mixed"])
 
 
 def test_mopup_s_wave_is_the_mopup_phase_of_window_waves():
@@ -95,22 +90,19 @@ def test_constraint_shapes_count_the_pods_terms():
     assert s["node_rows"] >= 2 * len(apps_aa)
 
 
-def test_tiny_traced_run_of_the_cell_reports_its_layers(tiny_root, tmp_path):
+def test_tiny_traced_run_of_the_cell_reports_its_layers(tiny_root):
     """The cell as BENCHMARK.json gives it (the guarded loop, its own traffic
     file), cut to the tiny size: correct, and its new readers report (the
     constrained roofline finds no Pallas kernel on the CPU and says None)."""
     import json
-    import shutil
 
     from benchmark import harness
 
-    root = str(tmp_path)
-    shutil.copytree(tiny_root, root, dirs_exist_ok=True)
-    spec = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    json.dump(spec, open(os.path.join(root, "BENCHMARK.json"), "w"))
-    _cell, _config, traffic = harness.resolve(spec, "wave-mixed-5k", root)
-    assert traffic["loop"] == "wave_guarded" and traffic["wave_pods"] == conftest.TINY["wave-mixed"][2]
-    result = harness.run_cell("wave-mixed-5k", 2**31 + 25, 3.0, True, time.perf_counter(), require_tpu=False, root=root,
+    spec = json.load(open(os.path.join(tiny_root, "BENCHMARK.json")))
+    cut = json.load(open(os.path.join(REPO, "benchmark", "tests", "tiny", "wave-mixed-5k.json")))
+    _cell, _config, traffic = harness.resolve(spec, "wave-mixed-5k", tiny_root)
+    assert traffic["loop"] == "wave_guarded" and traffic["wave_pods"] == cut["traffic"]["wave_pods"]
+    result = harness.run_cell("wave-mixed-5k", 2**31 + 25, 3.0, True, time.perf_counter(), require_tpu=False, root=tiny_root,
                               drain_seconds=10)
     assert result["correct"], result["checks"]
     assert {"mopup_s.wave", "explain_s.wave"} <= set(result["metrics"])
