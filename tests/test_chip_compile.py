@@ -123,3 +123,42 @@ def test_shard_map_program_compiles_on_2x2_mesh(topo, packed):
     text = run.lower(spec, {}).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text  # the cross-shard merges are real collectives
+
+
+def test_jobset_fleet_cycle_compiles_for_v5e(one_chip):
+    """The TPU-slice JobSet fleet at its full size (472 pools, 4,096 hosts,
+    a wave of 96 jobs against 354 running): exclusive families plus the gang
+    locality term, so the jnp choose tree and no kernel."""
+    from dataclasses import replace
+
+    from tpu_scheduler.models.profiles import DEFAULT_PROFILE
+    from tpu_scheduler.ops.assign import assign_cycle, split_device_arrays
+    from tpu_scheduler.ops.constraints import pack_constraints
+    from tpu_scheduler.ops.pack import pack_snapshot, pad_pods
+    from tpu_scheduler.testing import jobset_fleet
+    from tpu_scheduler.topology.locality import pack_topology
+    from tpu_scheduler.topology.model import TopologyModel
+
+    snap = jobset_fleet({"4x4": 256, "4x8": 128, "8x8": 64, "8x16": 16, "16x16": 8}, 0.75, 96, seed=0)
+    packed = pack_snapshot(snap)
+    cons = pack_constraints(snap, snap.pending_pods(), packed.padded_pods, packed.node_names, packed.padded_nodes)
+    assert cons.n_families == 1 and cons.n_terms == 0 and cons.n_pa_terms == 0
+    packed = replace(pad_pods(packed, cons.padded_pods), constraints=cons)
+    topo = pack_topology(TopologyModel.detect(snap.nodes).compile(snap.nodes), snap.pending_pods(),
+                         packed.padded_pods, packed.node_names, packed.padded_nodes)
+
+    def s(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+
+    nodes, pods = split_device_arrays({k: s(v) for k, v in packed.device_arrays().items()})
+    pods.update({k: s(v) for k, v in {**cons.pod_arrays(), **topo.pod_arrays()}.items()})
+    w = DEFAULT_PROFILE.weights()
+    compiled = assign_cycle.lower(
+        nodes, pods, jax.ShapeDtypeStruct(w.shape, w.dtype, sharding=one_chip),
+        max_rounds=DEFAULT_PROFILE.max_rounds, block=DEFAULT_PROFILE.pod_block, use_pallas=True,
+        cmeta={k: s(v) for k, v in cons.meta_arrays().items()}, cstate={k: s(v) for k, v in cons.state_arrays().items()},
+        hard_pa=False, tmeta={k: s(v) for k, v in topo.meta_arrays().items()},
+        tstate={k: s(v) for k, v in topo.state_arrays().items()},
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    _fits_v5e(compiled)

@@ -392,3 +392,32 @@ def test_no_topology_attach_for_gangless_or_unlabeled_clusters():
     sched2.run(until_settled=True)
     assert sched2.metrics.snapshot().get("scheduler_topology_cycles_total", 0) == 0
     assert sched2.metrics.snapshot().get("scheduler_gangs_admitted_total", 0) == 1
+
+
+def test_detect_reads_gke_slice_labels():
+    """GKE labels each multi-host TPU slice's nodes with their pool and
+    topology: a node with the topology label is in the slice its pool names;
+    a CPU pool's nodes (no topology label) are in no slice."""
+    from tpu_scheduler.topology.model import GKE_NODEPOOL_KEY, GKE_TPU_TOPOLOGY_KEY
+
+    nodes = [make_node(f"t{i}", labels={GKE_NODEPOOL_KEY: f"tpu-{i // 2}", GKE_TPU_TOPOLOGY_KEY: "2x4"}) for i in range(4)]
+    nodes += [make_node(f"c{i}", labels={GKE_NODEPOOL_KEY: "cpu-pool"}) for i in range(2)]
+    model = TopologyModel.detect(nodes)
+    assert [(lv.name, lv.key) for lv in model.levels] == [("slice", GKE_NODEPOOL_KEY)]
+    compiled = model.compile(nodes)
+    assert compiled.domains_of("t0") == compiled.domains_of("t1") == ("tpu-0",)
+    assert compiled.domains_of("t2") == ("tpu-1",)
+    assert compiled.domains_of("c0") == ("~c0",) and compiled.domains_of("c1") == ("~c1",)
+    # A pool label alone (no TPU topology) is no slice: the cluster stays topology-blind.
+    assert TopologyModel.detect([make_node("c", labels={GKE_NODEPOOL_KEY: "cpu-pool"})]) is None
+
+
+def test_detect_keeps_the_repos_own_labels_over_gke_ones():
+    from tpu_scheduler.topology.model import GKE_NODEPOOL_KEY, GKE_TPU_TOPOLOGY_KEY
+
+    nodes = [make_node(f"n{i}", labels={SLICE_KEY: f"s{i % 2}", RACK_KEY: "r0", GKE_NODEPOOL_KEY: "p", GKE_TPU_TOPOLOGY_KEY: "2x4"})
+             for i in range(4)]
+    model = TopologyModel.detect(nodes)
+    assert [(lv.name, lv.key) for lv in model.levels] == [("slice", SLICE_KEY), ("rack", RACK_KEY)]
+    assert model.compile(nodes).domains_of("n1") == ("s1", "r0")
+    assert TopologyModel.detect([make_node("plain", labels={"zone": "a"})]) is None

@@ -25,6 +25,7 @@ __all__ = [
     "Container",
     "LabelSelectorRequirement",
     "PodAntiAffinityTerm",
+    "exclusive_placement",
     "PodAffinityTerm",
     "WeightedPodAffinityTerm",
     "PodDisruptionBudget",
@@ -134,6 +135,29 @@ class PodAntiAffinityTerm:
 # own term — so the first pod of a self-affine group can place; without
 # self-match the pod is unschedulable until a match appears.
 PodAffinityTerm = PodAntiAffinityTerm
+
+
+def exclusive_placement(term: PodAntiAffinityTerm) -> tuple[str, str, str] | None:
+    """JobSet's exclusive-placement term shapes (``alpha.jobset.sigs.k8s.io/
+    exclusive-topology``): ``("aa", K, v)`` for the anti-affinity selector
+    ``{K Exists, K NotIn [v]}`` (it matches a pod labelled K with another
+    value than v), ``("pa", K, v)`` for the affinity selector ``{K In [v]}``
+    (a pod labelled K = v); None for any other selector, and for any
+    selector with ``matchLabels``."""
+    if term.match_labels:
+        return None
+    mx = term.match_expressions or ()
+    if len(mx) == 1:
+        (r,) = mx
+        if r.operator == "In" and r.values and len(r.values) == 1:
+            return ("pa", r.key, r.values[0])
+        return None
+    if len(mx) == 2:
+        ex = [r for r in mx if r.operator == "Exists"]
+        ni = [r for r in mx if r.operator == "NotIn"]
+        if len(ex) == 1 and len(ni) == 1 and ex[0].key == ni[0].key and ni[0].values and len(ni[0].values) == 1:
+            return ("aa", ni[0].key, ni[0].values[0])
+    return None
 
 
 @dataclass

@@ -54,6 +54,12 @@ class SchedulingBackend(abc.ABC):
     # locality gate would have its gangs deferred every cycle (starvation).
     supports_topology: bool = False
 
+    # Whether assign() runs the exclusive-family ops (ops/constraints.py
+    # ExclusiveFamilies).  The controller folds JobSet's exclusive-placement
+    # terms only for backends that say True; for the rest they stay
+    # per-term rows under the usual budgets.
+    supports_exclusive_families: bool = False
+
     def shard_for(self, index: int) -> "SchedulingBackend":
         """Backend instance for the ``index``-th parallel shard of a routed
         cycle (parallel/routing.py).  Default: this backend (serialized on

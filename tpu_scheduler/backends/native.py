@@ -35,6 +35,7 @@ _NULL = contextlib.nullcontext()
 class NativeBackend(SchedulingBackend):
     name = "native"
     supports_topology = True
+    supports_exclusive_families = True
 
     # shape: (packed: obj, profile: obj) -> ([P] i32, scalar i32, dict)
     def assign(self, packed: PackedCluster, profile: SchedulingProfile) -> tuple[np.ndarray, int]:
@@ -70,6 +71,7 @@ class NativeBackend(SchedulingBackend):
                 blocked_block,
                 constraint_commit,
                 constraint_filter,
+                pa_progress,
                 round_blocked_masks,
             )
 
@@ -195,14 +197,12 @@ class NativeBackend(SchedulingBackend):
                     avail = (avail.astype(np.int64) - dec[:n]).astype(np.int32)
                     was_active = active
                     active = cand & ~accepted
-                    if cons is not None and hard_pa:
+                    if cons is not None and (hard_pa or cons.n_families):
                         # Positive-affinity declarers blocked everywhere stay
                         # active while ANY pending PA term gained a match this
                         # round (mirrors ops/assign.py exactly — see its
                         # rationale).
-                        new_match = (cpods["pod_pa_matched"] * accepted[:, None].astype(np.float32)).sum(axis=0) > 0
-                        pa_hope = (cpods["pod_pa_declares"].sum(axis=1) > 0) & new_match.any()
-                        active = active | (was_active & ~has & pa_hope)
+                        active = active | (was_active & ~has & pa_progress(np, cpods, accepted, cmeta, hard_pa))
             rounds += 1
 
         out = np.full((p,), -1, dtype=np.int32)

@@ -49,6 +49,7 @@ _STACK_FN = None
 class TpuBackend(SchedulingBackend):
     name = "tpu"
     supports_topology = True
+    supports_exclusive_families = True
 
     def __init__(self, device=None, use_pallas: bool | None = None):
         try:

@@ -18,8 +18,8 @@ from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
-from ..api.objects import LabelSelectorRequirement, Node, Pod, full_name, total_pod_resources
-from .snapshot import ClusterSnapshot, NodeTable, PlacedOverlay, node_net_available
+from ..api.objects import LabelSelectorRequirement, Node, Pod, exclusive_placement, full_name, total_pod_resources
+from .snapshot import ClusterSnapshot, NodeTable, PlacedOverlay, node_net_available, node_topology_domain
 
 __all__ = [
     "InvalidNodeReason",
@@ -215,19 +215,6 @@ def term_matches(term, labels: dict[str, str] | None) -> bool:
     return selector_matches(term.match_labels, getattr(term, "match_expressions", None), labels)
 
 
-# shape: (node: obj, topology_key: str) -> obj
-def node_topology_domain(node: Node, topology_key: str) -> tuple[str, str]:
-    """The topology domain of a node under ``topology_key``.
-
-    Named domain ``(key, value)`` when the node carries the label; otherwise
-    the node is its own singleton domain ``("~node", name)`` — a keyless node
-    degrades to per-node (hostname-like) granularity.
-    """
-    labels = node.metadata.labels or {}
-    v = labels.get(topology_key)
-    return (topology_key, v) if v is not None else ("~node", node.name)
-
-
 def _placed_matching(
     snapshot: ClusterSnapshot, overlay: PlacedOverlay | None, namespace: str | None, match_labels
 ) -> Iterable[tuple[Pod, Node]]:
@@ -263,24 +250,44 @@ def make_affinity_checker(
     """
     my_terms = (pod.spec.anti_affinity or []) if pod.spec is not None else []
     my_ns = pod.metadata.namespace
+    my_labels = pod.metadata.labels or {}
     blocked: set[tuple[str, str]] = set()
     keys: set[str] = set()
 
-    # Direction A: domains holding a pod matched by one of my terms.
+    # Direction A: domains holding a pod matched by one of my terms.  An
+    # exclusive-placement term {K Exists, K NotIn [v]} matches the pods
+    # labelled K with another value: the snapshot's per-value index.
     for t in my_terms:
-        for q, qnode in _placed_matching(snapshot, overlay, my_ns, t.match_labels):
+        shape = exclusive_placement(t)
+        if shape is not None and shape[0] == "aa":
+            for x, doms in snapshot.label_domains(my_ns, shape[1], t.topology_key).items():
+                if x != shape[2]:
+                    blocked |= doms
+                    keys.add(t.topology_key)
+            placed = overlay.matching(my_ns, None) if overlay is not None else ()
+        else:
+            placed = _placed_matching(snapshot, overlay, my_ns, t.match_labels)
+        for q, qnode in placed:
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):
                 blocked.add(node_topology_domain(qnode, t.topology_key))
                 keys.add(t.topology_key)
-    # Direction B: domains of placed term-carriers whose term matches me.
-    carriers = snapshot.placed_pods_with_terms()
+    # Direction B: domains of placed term-carriers whose term matches me —
+    # the exclusive-placement carriers by value, the rest term by term.
+    for (k, tk), by_value in snapshot.exclusive_carrier_domains(my_ns).items():
+        x = my_labels.get(k)
+        if x is not None:
+            for v, doms in by_value.items():
+                if v != x:
+                    blocked |= doms
+                    keys.add(tk)
+    carriers = snapshot.placed_other_terms()
     if overlay is not None:
-        carriers = chain(carriers, overlay.carriers())
-    for q, qnode in carriers:
+        carriers = chain(carriers, ((q, qnode, q.spec.anti_affinity) for q, qnode in overlay.carriers()))
+    for q, qnode, terms in carriers:
         if q.metadata.namespace != my_ns:
             continue
-        for t in q.spec.anti_affinity:
-            if term_matches(t, pod.metadata.labels):
+        for t in terms:
+            if term_matches(t, my_labels):
                 blocked.add(node_topology_domain(qnode, t.topology_key))
                 keys.add(t.topology_key)
 
@@ -333,7 +340,14 @@ def make_pod_affinity_checker(
     term_domains: list[set[tuple[str, str]] | None] = []
     for t in my_terms:
         doms: set[tuple[str, str]] = set()
-        for q, qnode in _placed_matching(snapshot, overlay, my_ns, t.match_labels):
+        shape = exclusive_placement(t)
+        if shape is not None and shape[0] == "pa" and not exclude:
+            # {K In [v]}: the pods labelled K = v, from the snapshot's index.
+            doms |= snapshot.label_domains(my_ns, shape[1], t.topology_key).get(shape[2], set())
+            placed = overlay.matching(my_ns, None) if overlay is not None else ()
+        else:
+            placed = _placed_matching(snapshot, overlay, my_ns, t.match_labels)
+        for q, qnode in placed:
             if exclude and full_name(q) in exclude:
                 continue
             if q.metadata.namespace == my_ns and term_matches(t, q.metadata.labels):

@@ -20,7 +20,15 @@ import numpy as np
 from ..api.objects import Node, Pod, PodResources, is_extended_resource, is_pod_bound, total_pod_resources
 from ..api.quantity import cpu_to_millis, memory_to_bytes
 
-__all__ = ["ClusterSnapshot", "NodeTable", "PlacedOverlay", "node_allocatable", "node_net_available", "node_used_resources"]
+__all__ = [
+    "ClusterSnapshot",
+    "NodeTable",
+    "PlacedOverlay",
+    "node_allocatable",
+    "node_net_available",
+    "node_topology_domain",
+    "node_used_resources",
+]
 
 
 def node_allocatable(node: Node, snapshot: "ClusterSnapshot | None" = None) -> PodResources:
@@ -86,6 +94,11 @@ class ClusterSnapshot:
     _placed_by_label: dict | None = field(default=None, compare=False, repr=False)
     # Lazy per-key memo of the named topology domains (named_domains).
     _domains_by_key: dict[str, tuple[str, ...]] = field(default_factory=dict, compare=False, repr=False)
+    # Lazy indexes for JobSet's exclusive-placement terms (api/objects
+    # .exclusive_placement), whose selectors carry no matchLabels to index:
+    # label_domains / exclusive_carrier_domains memos, and the carriers'
+    # other terms (placed_other_terms).
+    _exclusive: dict = field(default_factory=dict, compare=False, repr=False)
     # Lazy column view of the nodes (node_table), built by the first
     # why-pending breakdown a snapshot serves.
     _node_table: "NodeTable | None" = field(default=None, compare=False, repr=False)
@@ -146,6 +159,55 @@ class ClusterSnapshot:
             object.__setattr__(self, "_placed_by_label", index)
         return min((index.get((namespace, k, v), ()) for k, v in match_labels.items()), key=len)
 
+    def label_domains(self, namespace: str | None, key: str, topology_key: str) -> dict[str, set]:
+        """value -> the topology domains (node_topology_domain under
+        ``topology_key``) of the placed pods in ``namespace`` labelled
+        ``key = value``; built once a snapshot per (namespace, key, topology
+        key)."""
+        memo_key = ("labels", namespace, key, topology_key)
+        out = self._exclusive.get(memo_key)
+        if out is None:
+            out = {}
+            for q, qnode in self._placed:
+                v = (q.metadata.labels or {}).get(key)
+                if v is not None and q.metadata.namespace == namespace:
+                    out.setdefault(v, set()).add(node_topology_domain(qnode, topology_key))
+            self._exclusive[memo_key] = out
+        return out
+
+    def exclusive_carrier_domains(self, namespace: str | None) -> dict[tuple[str, str], dict[str, set]]:
+        """(label key K, topology key) -> v -> the domains of the placed pods
+        in ``namespace`` carrying the exclusive anti-affinity term ``{K
+        Exists, K NotIn [v]}`` over that topology key."""
+        self._split_carriers()
+        return self._exclusive[("carriers", namespace)] if ("carriers", namespace) in self._exclusive else {}
+
+    def placed_other_terms(self) -> list:
+        """(pod, node, terms) of every placed carrier, with its anti-affinity
+        terms other than the exclusive ones exclusive_carrier_domains holds
+        (carriers left with none are left out)."""
+        self._split_carriers()
+        return self._exclusive["other"]
+
+    def _split_carriers(self) -> None:
+        if "other" in self._exclusive:
+            return
+        from ..api.objects import exclusive_placement
+
+        other = []
+        for q, qnode in self._placed_with_terms:
+            rest = []
+            for t in q.spec.anti_affinity:
+                shape = exclusive_placement(t)
+                if shape is not None and shape[0] == "aa":
+                    by_v = self._exclusive.setdefault(("carriers", q.metadata.namespace), {}).setdefault((shape[1], t.topology_key), {})
+                    by_v.setdefault(shape[2], set()).add(node_topology_domain(qnode, t.topology_key))
+                else:
+                    rest.append(t)
+            if rest:
+                other.append((q, qnode, rest))
+        self._exclusive["other"] = other
+
     def named_domains(self, topology_key: str) -> tuple[str, ...]:
         """The distinct values of ``topology_key`` over the nodes, in node
         order — the key's named domains, memoized per key (a hard-spread
@@ -179,6 +241,19 @@ class ClusterSnapshot:
                 self, "_pending", [p for p in self.pods if p.status.phase == "Pending" and not is_pod_bound(p)]
             )
         return self._pending
+
+
+# shape: (node: obj, topology_key: str) -> obj
+def node_topology_domain(node: Node, topology_key: str) -> tuple[str, str]:
+    """The topology domain of a node under ``topology_key``.
+
+    Named domain ``(key, value)`` when the node carries the label; otherwise
+    the node is its own singleton domain ``("~node", name)`` — a keyless node
+    degrades to per-node (hostname-like) granularity.
+    """
+    labels = node.metadata.labels or {}
+    v = labels.get(topology_key)
+    return (topology_key, v) if v is not None else ("~node", node.name)
 
 
 class NodeTable:

@@ -87,6 +87,9 @@ _CONSTRAINT_KEYS = (
     "pod_ppa_w",
     "pod_ppa_matched",
 )
+# Exclusive-family pod-side keys (present only when the cycle folds JobSet's
+# exclusive-placement terms, ops/constraints.ExclusiveFamilies).
+_EXCLUSIVE_KEYS = ("pod_ex_label", "pod_ex_carry", "pod_ex_aff")
 
 
 # shape: (arrays: dict) -> (dict, dict)
@@ -211,10 +214,11 @@ def _choose_block(avail, nodes, weights, blk, pallas_pack=None, round_masks=None
 def uses_kernel(use_pallas: bool, pods: dict, nodes: dict, topology: bool) -> bool:
     """Whether a cycle's choose step runs the fused Pallas kernel — the one
     place the decision is made.  The kernel has no gang-locality operand
-    yet, so topology cycles run the jnp expression tree (bit-identical to
-    native by construction — the term is the same xp tree on both
+    and no exclusive-family operand yet, so topology cycles and cycles that
+    fold exclusive families run the jnp expression tree (bit-identical to
+    native by construction — the terms are the same xp trees on both
     backends); otherwise the widths must fit it."""
-    if not use_pallas or topology:
+    if not use_pallas or topology or "pod_ex_carry" in pods:
         return False
     from .pallas_choose import pallas_kernel_supported
 
@@ -264,6 +268,8 @@ def _choose(
         )
 
     choose_keys = _CHOOSE_KEYS + (_CONSTRAINT_KEYS if round_masks is not None else ())
+    if "pod_ex_carry" in ps:
+        choose_keys = choose_keys + _EXCLUSIVE_KEYS
     if topo_t is not None:
         choose_keys = choose_keys + ("pod_gang_id",)
     if block >= p:
@@ -367,6 +373,7 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
     back into those counts.  Gang-count state is [G, N] — NOT pod-indexed —
     so the size-chain slicing never loses placed-member information."""
     n = nodes["node_avail"].shape[0]
+    exclusive = cmeta is not None and "ex_node_dom" in cmeta  # exclusive families (ops/constraints.py)
 
     def body(state):
         avail, ps, n_active, rounds, cst, tst = state
@@ -423,7 +430,7 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
         avail = avail - dec[:n]
         was_active = ps["active"]
         ps["active"] = cand & ~accepted
-        if cmeta is not None and hard_pa:
+        if cmeta is not None and (hard_pa or exclusive):
             # Positive affinity breaks the "feasibility only shrinks" rule
             # the no-feasible-node drop-out relies on: a pod placed THIS
             # round can activate a declarer's term and open nodes for it.
@@ -437,9 +444,9 @@ def _make_round_body(nodes, weights, block, use_pallas, pallas_interpret, cmeta,
             # round 4's any-pod-placed rule pinned through the whole
             # flagship tail (measured: ~1.3k pods blocking the size chain)
             # drain as soon as PA progress stops.
-            new_match = (ps["pod_pa_matched"] * accepted[:, None].astype(jnp.float32)).sum(axis=0) > 0  # [Ta]
-            pa_hope = (ps["pod_pa_declares"].sum(axis=1) > 0) & new_match.any()
-            ps["active"] = ps["active"] | (was_active & ~has & pa_hope)
+            from .constraints import pa_progress
+
+            ps["active"] = ps["active"] | (was_active & ~has & pa_progress(jnp, ps, accepted, cmeta, hard_pa))
         if tmeta is not None:
             # Commit accepted gang members into the [G, N] placement counts
             # (non-claimants carry the sentinel column, gangless pods row 0 —

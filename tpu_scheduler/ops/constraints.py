@@ -93,12 +93,12 @@ is an accelerator, never a semantics change.
 from __future__ import annotations
 
 import contextlib
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import numpy as np
 
-from ..api.objects import Pod
-from ..core.predicates import term_matches
+from ..api.objects import Pod, exclusive_placement
+from ..core.predicates import node_topology_domain, term_matches
 from .pack import round_up
 
 __all__ = [
@@ -254,6 +254,176 @@ def _aa_key(ns, term) -> tuple:
     return (ns, term.topology_key, _canon_selector(term.match_labels, term.match_expressions))
 
 
+# ---------------------------------------------------------------------------
+# Exclusive-placement families (JobSet's ``exclusive-topology`` term pair)
+# ---------------------------------------------------------------------------
+#
+# JobSet places each job alone in a domain of key D (a TPU slice's node pool)
+# by giving every pod of job ``v`` two required terms over D: pod affinity
+# ``K In [v]`` and anti-affinity ``K Exists, K NotIn [v]`` (K the job-key
+# label).  Per-term rows would spend one anti-affinity row per job and one
+# coarse column per pool, so a fleet of a few hundred jobs or pools passes
+# the budgets above.  A FAMILY (namespace, D, K) folds every such term into
+# two summaries per domain d of D, each "none", one value id, or "many":
+#
+#   M[d]  the K values the pods in d carry as labels;
+#   C[d]  the v of the pods in d that carry the anti-affinity term.
+#
+# A carrier of ``v`` may enter d only if M[d] is none or v; a pod labelled
+# ``K = x`` only if C[d] is none or x — exact for any mix of labelled pods
+# and carriers.  A pod-affinity declarer of ``a`` may enter only a domain
+# whose M[d] is a, or anywhere while no pod anywhere carries a and it carries
+# a itself (the bootstrap the per-term rows implement; ``seen[a]`` tracks
+# "some pod carries a").  A domain reading "many" cannot say whether it holds
+# a, so a family with such a domain at pack time keeps per-term rows for its
+# pod-affinity terms (a domain that turns "many" inside a cycle only blocks
+# more).  State is [F, D_k] per family and [F, V] per value, read through a
+# [F, N] node -> domain index: no budget above applies.
+EX_NONE = -1
+EX_MANY = -2
+
+
+@dataclass(frozen=True)
+class ExclusiveFamilies:
+    """The folded families of one cycle: pod-side value ids, the node ->
+    domain index, and the per-domain and per-value state (see above)."""
+
+    pod: dict  # pod_ex_label / pod_ex_carry / pod_ex_aff: [P, F] int32, -1 = none
+    meta: dict  # ex_node_dom [F, N] int32; ex_aff_declared [F, V] f32
+    state: dict  # ex_m / ex_c [F, D_k] int32 (EX_NONE / EX_MANY / id); ex_seen [F, V] f32
+    n_families: int
+    folded_aa: frozenset  # canonical anti-affinity keys that left the per-term rows
+    folded_pa: frozenset  # canonical pod-affinity keys that left the per-term rows
+
+
+# shape: (snapshot: obj, pending: obj, placed_carrier_keys: obj, aa_vocab: dict,
+#   pa_vocab: dict, declared: fn, p_pad: int, n_pad: int, pad: fn) -> obj
+def _pack_exclusive(snapshot, pending, placed_carrier_keys, aa_vocab, pa_vocab, declared, p_pad, n_pad, pad):
+    """The cycle's :class:`ExclusiveFamilies`, or None when no term has the
+    exclusive shape.  A family folds its anti-affinity terms when no pod
+    carries two of them, and its pod-affinity terms when no pending pod
+    declares two and no domain reads "many"; what does not fold keeps its
+    per-term rows.  The placed pods' summaries come from the snapshot's
+    exclusive-term indexes (ClusterSnapshot.label_domains,
+    exclusive_carrier_domains)."""
+    aa_shape = {}  # key -> (family, v)
+    for key, (ns, t) in aa_vocab.items():
+        s = exclusive_placement(t)
+        if s is not None and s[0] == "aa":
+            aa_shape[key] = ((ns, t.topology_key, s[1]), s[2])
+    pa_shape = {}
+    for key, (ns, t) in pa_vocab.items():
+        s = exclusive_placement(t)
+        if s is not None and s[0] == "pa":
+            pa_shape[key] = ((ns, t.topology_key, s[1]), s[2])
+    if not aa_shape and not pa_shape:
+        return None
+
+    # Families a pod declares twice stay per-term (one value id a pod).
+    no_aa: set = set()
+    no_pa: set = set()
+    for aa_d in [declared(p)[0] for p in pending] + [d for _q, _qn, d in placed_carrier_keys]:
+        fams = [aa_shape[k][0] for k, _t in aa_d if k in aa_shape]
+        no_aa.update(f for f in fams if fams.count(f) > 1)
+    for p in pending:
+        fams = [pa_shape[k][0] for k, _t in declared(p)[1] if k in pa_shape]
+        no_pa.update(f for f in fams if fams.count(f) > 1)
+
+    values: dict[str, int] = {}
+
+    def vid(v):
+        return values.setdefault(v, len(values))
+
+    # Node -> domain id under each family's topology key; a keyless node is
+    # its own domain, as core/snapshot.node_topology_domain makes it.
+    dom_index: dict[str, tuple[np.ndarray, dict]] = {}
+
+    def domains(d_key):
+        if d_key not in dom_index:
+            doms: dict = {}
+            ids = np.zeros((n_pad,), dtype=np.int32)
+            for i, n in enumerate(snapshot.nodes):
+                ids[i] = doms.setdefault(node_topology_domain(n, d_key), len(doms))
+            dom_index[d_key] = (ids, doms)
+        return dom_index[d_key][1]
+
+    def summary(by_value: dict, d_key: str) -> dict:
+        """domain id -> the one value id placed there, or EX_MANY (a
+        domain left out holds none)."""
+        out: dict = {}
+        doms = domains(d_key)
+        for v, placed_in in by_value.items():
+            i = vid(v)
+            for d in placed_in:
+                s = out.get(doms[d], i)
+                out[doms[d]] = i if s == i else EX_MANY
+        return out
+
+    labelled = {
+        f: snapshot.label_domains(f[0], f[2], f[1])
+        for f in sorted({f for f, _v in aa_shape.values()} | {f for f, _v in pa_shape.values()})
+    }
+    m_sum = {f: summary(by_value, f[1]) for f, by_value in labelled.items()}
+    fold_aa = {k for k, (f, _v) in aa_shape.items() if f not in no_aa}
+    fold_pa = {k for k, (f, _v) in pa_shape.items() if f not in no_pa and EX_MANY not in m_sum[f].values()}
+    used = sorted({aa_shape[k][0] for k in fold_aa} | {pa_shape[k][0] for k in fold_pa})
+    if not used:
+        return None
+    c_sum = {f: summary(snapshot.exclusive_carrier_domains(f[0]).get((f[2], f[1]), {}), f[1]) for f in used}
+
+    n_fam = len(used)
+    col = {f: i for i, f in enumerate(used)}
+    rows = []
+    for p in pending:
+        ns, labels = p.metadata.namespace, p.metadata.labels or {}
+        aa_d, pa_d = declared(p)[0], declared(p)[1]
+        lab = [vid(labels[f[2]]) if f[0] == ns and f[2] in labels else EX_NONE for f in used]
+        car = [EX_NONE] * n_fam
+        aff = [EX_NONE] * n_fam
+        for key, _t in aa_d:
+            if key in fold_aa:
+                f, v = aa_shape[key]
+                car[col[f]] = vid(v)
+        for key, _t in pa_d:
+            if key in fold_pa:
+                f, v = pa_shape[key]
+                aff[col[f]] = vid(v)
+        rows.append((lab, car, aff))
+
+    f_pad = pad("f", n_fam, 1)
+    dk_pad = pad("exd", max(len(dom_index[f[1]][1]) for f in used), 8)
+    v_pad = pad("exv", len(values), 8)
+    pod_label = np.full((p_pad, f_pad), EX_NONE, dtype=np.int32)
+    pod_carry = np.full((p_pad, f_pad), EX_NONE, dtype=np.int32)
+    pod_aff = np.full((p_pad, f_pad), EX_NONE, dtype=np.int32)
+    for pi, (lab, car, aff) in enumerate(rows):
+        pod_label[pi, :n_fam] = lab
+        pod_carry[pi, :n_fam] = car
+        pod_aff[pi, :n_fam] = aff
+    node_dom = np.zeros((f_pad, n_pad), dtype=np.int32)
+    ex_m = np.full((f_pad, dk_pad), EX_NONE, dtype=np.int32)
+    ex_c = np.full((f_pad, dk_pad), EX_NONE, dtype=np.int32)
+    ex_seen = np.zeros((f_pad, v_pad), dtype=np.float32)
+    declared_aff = np.zeros((f_pad, v_pad), dtype=np.float32)
+    for i, f in enumerate(used):
+        node_dom[i] = dom_index[f[1]][0]
+        for d, s in m_sum[f].items():
+            ex_m[i, d] = s
+        for d, s in c_sum[f].items():
+            ex_c[i, d] = s
+        ex_seen[i, [values[x] for x in labelled[f]]] = 1.0
+    pi, fi = np.nonzero(pod_aff >= 0)
+    declared_aff[fi, pod_aff[pi, fi]] = 1.0
+    return ExclusiveFamilies(
+        pod={"pod_ex_label": pod_label, "pod_ex_carry": pod_carry, "pod_ex_aff": pod_aff},
+        meta={"ex_node_dom": node_dom, "ex_aff_declared": declared_aff},
+        state={"ex_m": ex_m, "ex_c": ex_c, "ex_seen": ex_seen},
+        n_families=n_fam,
+        folded_aa=frozenset(fold_aa),
+        folded_pa=frozenset(fold_pa),
+    )
+
+
 def _sp_key(ns, c) -> tuple:
     return (ns, c.topology_key, int(c.max_skew), _canon_selector(c.match_labels, c.match_expressions))
 
@@ -312,9 +482,20 @@ class ConstraintSet:
     n_ppa_terms: int
     n_spread: int
     n_spread_soft: int
+    exclusive: ExclusiveFamilies | None = None
+
+    @property
+    def n_families(self) -> int:
+        return 0 if self.exclusive is None else self.exclusive.n_families
+
+    @property
+    def padded_pods(self) -> int:
+        return self.pod_aa_carries.shape[0]
 
     def pod_arrays(self) -> dict:
+        ex = {} if self.exclusive is None else self.exclusive.pod
         return {
+            **ex,
             "pod_aa_carries": self.pod_aa_carries,
             "pod_aa_matched": self.pod_aa_matched,
             "pod_pa_declares": self.pod_pa_declares,
@@ -328,7 +509,9 @@ class ConstraintSet:
         }
 
     def meta_arrays(self) -> dict:
+        ex = {} if self.exclusive is None else self.exclusive.meta
         return {
+            **ex,
             "node_dom_c": self.node_dom_c,
             "term_uses_dom": self.term_uses_dom,
             "pa_uses_dom": self.pa_uses_dom,
@@ -340,7 +523,9 @@ class ConstraintSet:
         }
 
     def state_arrays(self) -> dict:
+        ex = {} if self.exclusive is None else self.exclusive.state
         return {
+            **ex,
             "aa_dom_m": self.aa_dom_m,
             "aa_dom_c": self.aa_dom_c,
             "aa_node_m": self.aa_node_m,
@@ -366,6 +551,7 @@ def pack_constraints(
     label_block: int = 8,
     match_memo: dict | None = None,
     widths: dict | None = None,
+    exclusive: bool = True,
 ) -> ConstraintSet | None:
     """Build constraint tensors for one cycle; None if nothing constrained.
 
@@ -387,7 +573,11 @@ def pack_constraints(
     domain axis pad to the widest it has been: a cycle whose distinct-term
     count dips below a ``label_block`` multiple then reuses the compiled
     programs instead of compiling new ones (a 29 s solve on a v5e at the
-    5,000-node envelope).  The extra columns are zero, as padding is."""
+    5,000-node envelope).  The extra columns are zero, as padding is.
+
+    ``exclusive`` folds JobSet's exclusive-placement terms into families
+    (:class:`ExclusiveFamilies`, under a ``exclusive`` span) for backends
+    whose round engine runs them; without it those terms are per-term rows."""
     nodes = list(snapshot.nodes)
     assert tuple(n.name for n in nodes) == tuple(node_names)
 
@@ -452,7 +642,35 @@ def pack_constraints(
         for key, t in aa_d:
             aa_vocab.setdefault(key, (ns, t))
 
-    if not aa_vocab and not pa_vocab and not ppa_vocab and not sp_vocab and not sps_vocab:
+    def pad(axis: str, n: int, block: int = label_block) -> int:
+        w = round_up(max(n, 1), block)
+        if widths is not None:
+            w = widths[axis] = max(w, widths.get(axis, 0))
+        return w
+
+    families = None
+    if exclusive:
+        from ..utils.tracing import span
+
+        with span("exclusive"):
+            families = _pack_exclusive(
+                snapshot, pending, placed_carrier_keys, aa_vocab, pa_vocab, _declared, padded_pods, padded_nodes, pad
+            )
+        if families is not None:
+            aa_vocab = {k: v for k, v in aa_vocab.items() if k not in families.folded_aa}
+            pa_vocab = {k: v for k, v in pa_vocab.items() if k not in families.folded_pa}
+            # A JobSet wave's pending count moves with the slice shapes its
+            # jobs draw: the pod axis rounds up to a power of two and keeps
+            # the widest it has had, so waves of other sizes reuse the
+            # compiled programs.  The caller pads its own pod arrays to
+            # match (ops/pack.pad_pods).
+            rows = pad("p", 1 << (padded_pods - 1).bit_length(), 1)
+            if rows > padded_pods:
+                grown = {k: np.pad(v, ((0, rows - padded_pods), (0, 0)), constant_values=EX_NONE) for k, v in families.pod.items()}
+                families = replace(families, pod=grown)
+                padded_pods = rows
+
+    if not aa_vocab and not pa_vocab and not ppa_vocab and not sp_vocab and not sps_vocab and families is None:
         return None
     if len(aa_vocab) > max_aa_terms:
         raise UntensorizableConstraints(f"{len(aa_vocab)} anti-affinity terms > budget {max_aa_terms}")
@@ -498,12 +716,6 @@ def pack_constraints(
                 raise UntensorizableConstraints(f"spread key {k!r} is per-node-granular ({len(vals)} values)")
         else:
             raise UntensorizableConstraints(f"topology key {k!r} has {len(vals)} shared-value domains > budget")
-
-    def pad(axis: str, n: int) -> int:
-        w = round_up(max(n, 1), label_block)
-        if widths is not None:
-            w = widths[axis] = max(w, widths.get(axis, 0))
-        return w
 
     d_pad = pad("d", len(dom_vocab))
     t_pad = pad("t", len(aa_vocab))
@@ -617,9 +829,11 @@ def pack_constraints(
     for pi, p in enumerate(pending):
         aa_d, pa_d, ppa_d, sp_d, sps_d = _declared(p)
         for key, _t in aa_d:
-            pod_aa_carries[pi, aa_index[key]] = 1.0
+            if key in aa_index:  # folded keys live in the families
+                pod_aa_carries[pi, aa_index[key]] = 1.0
         for key, _t in pa_d:
-            pod_pa_declares[pi, pa_index[key]] = 1.0
+            if key in pa_index:
+                pod_pa_declares[pi, pa_index[key]] = 1.0
         for key, _t, w in ppa_d:
             pod_ppa_w[pi, ppa_index[key]] += w
         for key, _c in sp_d:
@@ -694,7 +908,8 @@ def pack_constraints(
                         sps_counts[si, dom_vocab[(c.topology_key, v)]] += 1.0
         for _q, qnode, aa_d in placed_carrier_keys:
             for key, t in aa_d:
-                _mark(aa_dom_c, aa_node_c, aa_index[key], t, qnode.name)
+                if key in aa_index:
+                    _mark(aa_dom_c, aa_node_c, aa_index[key], t, qnode.name)
 
     return ConstraintSet(
         pod_aa_carries=pod_aa_carries,
@@ -730,6 +945,7 @@ def pack_constraints(
         n_ppa_terms=len(ppa_terms),
         n_spread=len(sp_terms),
         n_spread_soft=len(sps_terms),
+        exclusive=families,
     )
 
 
@@ -807,7 +1023,40 @@ def round_blocked_masks(
         # Preferred inter-pod terms: per-term match COUNT at each node's
         # domain; score_block adds pod_ppa_w (signed weights) @ this.
         masks["ppa_cnt_node"] = state["ppa_dom_cnt"] @ ndc_t + state["ppa_node_cnt"]
+    if "ex_m" in state:
+        # Exclusive families: each node reads its domain's summaries.
+        node_dom = meta["ex_node_dom"]
+        masks["ex_m_node"] = xp.take_along_axis(state["ex_m"], node_dom, axis=1)  # [F, N]
+        masks["ex_c_node"] = xp.take_along_axis(state["ex_c"], node_dom, axis=1)
+        masks["ex_seen"] = state["ex_seen"]
     return masks
+
+
+# shape: (table: [F, V] any, ids: [B, F] i32) -> [B, F] any
+def _per_family(xp, table, ids):
+    """``table[f, ids[b, f]]`` (ids below 0 read column 0; callers mask them)."""
+    f, v = table.shape
+    return table.reshape(-1)[xp.arange(f, dtype=xp.int32)[None, :] * v + xp.maximum(ids, 0)]
+
+
+# shape: (blk: dict, masks: dict) -> [B, N] bool
+def exclusive_blocked(xp, blk: dict, masks: dict):
+    """[B, N] nodes the exclusive families block for one pod block: a
+    carrier of v where the domain holds a label other than v, a pod
+    labelled x where the domain holds a carrier of another value, and a
+    pod-affinity declarer of a where the domain's labels are not a alone —
+    unless a is carried nowhere and the pod carries it (the bootstrap)."""
+    car = blk["pod_ex_carry"][:, :, None]  # [B, F, 1]
+    lab = blk["pod_ex_label"][:, :, None]
+    aff = blk["pod_ex_aff"]
+    m = masks["ex_m_node"][None, :, :]  # [1, F, N]
+    c = masks["ex_c_node"][None, :, :]
+    waived = (blk["pod_ex_label"] == aff) & (_per_family(xp, masks["ex_seen"], aff) == 0)  # [B, F]
+    hold = (aff >= 0) & ~waived
+    b = (car >= 0) & (m != EX_NONE) & (m != car)
+    b = b | ((lab >= 0) & (c != EX_NONE) & (c != lab))
+    b = b | (hold[:, :, None] & (m != aff[:, :, None]))
+    return b.any(axis=1)
 
 
 # shape: (blk: dict, masks: dict) -> any
@@ -825,6 +1074,8 @@ def blocked_block(xp, blk: dict, masks: dict):
     if "pa_unmatched_node" in masks:
         gated = blk["pod_pa_declares"] * (1.0 - blk["pod_pa_matched"] * masks["pa_inactive"][None, :])
         b = b + gated @ masks["pa_unmatched_node"]
+    if "ex_m_node" in masks:
+        return (b > 0) | exclusive_blocked(xp, blk, masks)
     return b > 0
 
 
@@ -1009,6 +1260,56 @@ def _null_span(name):
     return _NULL_SPAN_CTX
 
 
+# shape: (cell: [A, F] i32, size: int, rank: [A, 1] f32, live: [A, F] bool,
+#   val: [A, F] i32) -> ([A, F] f32, [A, F] f32, [A, F] f32)
+def _first_two(xp, cell, size: int, rank, live, val):
+    """Per pod, in its own cell: the least rank of a live pod, that pod's
+    value, and the least rank of a live pod whose value differs from it."""
+    flat = cell.reshape(-1)
+    vf = val.astype(xp.float32)
+    min1 = _scatter_min(xp, size, flat, xp.where(live, rank, RANK_INF).reshape(-1))[cell]
+    v1 = -_scatter_min(xp, size, flat, xp.where(live & (rank == min1), -vf, RANK_INF).reshape(-1))[cell]
+    min2 = _scatter_min(xp, size, flat, xp.where(live & (vf != v1), rank, RANK_INF).reshape(-1))[cell]
+    return min1, v1, min2
+
+
+# shape: (pw: dict, choice: [A] i32, rank: [A] f32, acc: [A] bool, meta: dict,
+#   dk: int) -> [A] bool
+def _exclusive_conflicts(xp, pw: dict, choice, rank, acc, meta: dict, dk: int):
+    """Within-round anti-affinity of the exclusive families: a pod loses to
+    an earlier-rank accepted pod in its domain whose term it matches (a
+    carrier of another value than its label) or which matches its term (a
+    label other than its value) — the per-term rule, pair for pair."""
+    node_dom = meta["ex_node_dom"]
+    f = node_dom.shape[0]
+    cell = xp.arange(f, dtype=xp.int32)[None, :] * dk + node_dom.T[choice]  # [A, F]
+    r = rank[:, None]
+    lab, car = pw["pod_ex_label"], pw["pod_ex_carry"]
+    live = acc[:, None]
+    c_min1, c_v1, c_min2 = _first_two(xp, cell, f * dk, r, live & (car >= 0), car)
+    l_min1, l_v1, l_min2 = _first_two(xp, cell, f * dk, r, live & (lab >= 0), lab)
+    labf, carf = lab.astype(xp.float32), car.astype(xp.float32)
+    bad = (lab >= 0) & (((c_min1 < r) & (c_v1 != labf)) | (c_min2 < r))
+    bad = bad | ((car >= 0) & (((l_min1 < r) & (l_v1 != carf)) | (l_min2 < r)))
+    return bad.any(axis=1)
+
+
+# shape: (pw: dict, rank: [A] f32, keep: [A] bool, state: dict) -> [A] bool
+def _exclusive_bootstrap_conflicts(xp, pw: dict, rank, keep, state: dict):
+    """Within-round pod affinity of the exclusive families, the per-term
+    bootstrap rule: of the kept pods carrying a value no pod carried at round
+    start, only the least-ranked may rely on the waiver."""
+    seen = state["ex_seen"]
+    f, v = seen.shape
+    lab, aff = pw["pod_ex_label"], pw["pod_ex_aff"]
+    r = rank[:, None]
+    base = xp.arange(f, dtype=xp.int32)[None, :] * v
+    first = _scatter_min(xp, f * v, (base + xp.maximum(lab, 0)).reshape(-1),
+                         xp.where(keep[:, None] & (lab >= 0), r, RANK_INF).reshape(-1))
+    waived = (aff >= 0) & (lab == aff) & (_per_family(xp, seen, aff) == 0)
+    return (waived & keep[:, None] & (r > first[base + xp.maximum(aff, 0)])).any(axis=1)
+
+
 # shape: (state: dict, meta: dict) -> dict
 def augment_round_state(xp, state: dict, meta: dict) -> dict:
     """Derive the ROUND-CARRIED conflict-state entries from a cycle-start
@@ -1065,6 +1366,9 @@ def constraint_filter(
     ws_keys = ["pod_aa_carries", "pod_aa_matched", "pod_sp_declares", "pod_sp_matched"]
     if hard_pa:
         ws_keys += ["pod_pa_declares", "pod_pa_matched"]
+    exclusive = "ex_m" in state
+    if exclusive:
+        ws_keys += ["pod_ex_label", "pod_ex_carry", "pod_ex_aff"]
     if xp is np:
         gperm = np.flatnonzero(accepted)
         if gperm.size == 0:
@@ -1156,6 +1460,8 @@ def constraint_filter(
             min_m_at = mins[g + t * cells]
             bad_aa = ((matc > 0) & (rank_f[:, None] > min_c_at)) | ((carr > 0) & (rank_f[:, None] > min_m_at))
         keep = acc_ws & ~bad_aa.any(axis=1)
+        if exclusive:
+            keep = keep & ~_exclusive_conflicts(xp, pw, choice_ws, rank_f, acc_ws, meta, state["ex_m"].shape[1])
 
     # ---- positive affinity bootstrap (within-round) -----------------------
     # A term inactive at round start was waived for self-matching declarers
@@ -1169,15 +1475,20 @@ def constraint_filter(
     # filter may drop) only defers more — never admits a violation.  This
     # family cannot ride the AA segment scatter: its min is over the
     # POST-AA keep set, a sequential dependency.
-    if hard_pa:
+    if hard_pa or exclusive:
         with sp_span("pa"):
-            pa_inactive_f = state["pa_inactive"]
-            keep_pa_f = keep.astype(xp.float32)
-            pa_m_acc = pw["pod_pa_matched"] * keep_pa_f[:, None]  # [A, Ta]
-            min_match_rank = xp.min(xp.where(pa_m_acc > 0, rank_f[:, None], RANK_INF), axis=0)  # [Ta]
-            waived = pw["pod_pa_declares"] * pw["pod_pa_matched"] * pa_inactive_f[None, :]  # [A, Ta]
-            bad_pa = (waived > 0) & keep[:, None] & (rank_f[:, None] > min_match_rank[None, :])
-            keep = keep & ~bad_pa.any(axis=1)
+            bad_pa = xp.zeros_like(keep)
+            if hard_pa:
+                pa_inactive_f = state["pa_inactive"]
+                keep_pa_f = keep.astype(xp.float32)
+                pa_m_acc = pw["pod_pa_matched"] * keep_pa_f[:, None]  # [A, Ta]
+                min_match_rank = xp.min(xp.where(pa_m_acc > 0, rank_f[:, None], RANK_INF), axis=0)  # [Ta]
+                waived = pw["pod_pa_declares"] * pw["pod_pa_matched"] * pa_inactive_f[None, :]  # [A, Ta]
+                bad_pa = ((waived > 0) & keep[:, None] & (rank_f[:, None] > min_match_rank[None, :])).any(axis=1)
+            if exclusive:
+                # Family twin, judged on the same post-anti-affinity keep set.
+                bad_pa = bad_pa | _exclusive_bootstrap_conflicts(xp, pw, rank_f, keep, state)
+            keep = keep & ~bad_pa
 
     # ---- topology spread (rank-prefix admission + in-round cascade) -------
     # The scalar rule (core/predicates.make_spread_checker): placing a
@@ -1253,6 +1564,57 @@ def constraint_filter(
         return out
     full = xp.zeros_like(accepted).at[gperm].set(keep)
     return accepted & full
+
+
+# shape: (summary: [F, D] i32, lo: [F, D] f32, hi: [F, D] f32) -> [F, D] i32
+def _ex_merge(xp, summary, lo, hi):
+    """Fold a round's landing values (least ``lo``, greatest ``hi`` per
+    domain; ``lo`` RANK_INF where none landed) into a family summary."""
+    landed = lo < RANK_INF
+    lo_i = xp.where(landed, lo, 0.0).astype(xp.int32)
+    hi_i = xp.where(landed, hi, 0.0).astype(xp.int32)
+    one = (lo_i == hi_i) & ((summary == EX_NONE) | (summary == lo_i))
+    return xp.where(landed, xp.where(one, lo_i, EX_MANY), summary)
+
+
+# shape: (accepted: [P] bool, choice: [P] i32, ps: dict, state: dict, meta: dict) -> dict
+def _exclusive_commit(xp, accepted, choice, ps: dict, state: dict, meta: dict) -> dict:
+    """Fold the round's accepted pods into the families' summaries."""
+    f, dk = state["ex_m"].shape
+    v = state["ex_seen"].shape[1]
+    cell = (xp.arange(f, dtype=xp.int32)[None, :] * dk + meta["ex_node_dom"].T[choice]).reshape(-1)  # [P·F]
+    out = {}
+    for name, key in (("ex_m", "pod_ex_label"), ("ex_c", "pod_ex_carry")):
+        val = ps[key]
+        live = (accepted[:, None] & (val >= 0)).reshape(-1)
+        vf = val.astype(xp.float32).reshape(-1)
+        lo = _scatter_min(xp, f * dk, cell, xp.where(live, vf, RANK_INF)).reshape(f, dk)
+        hi = -_scatter_min(xp, f * dk, cell, xp.where(live, -vf, RANK_INF)).reshape(f, dk)
+        out[name] = _ex_merge(xp, state[name], lo, hi)
+    lab = ps["pod_ex_label"]
+    vcell = (xp.arange(f, dtype=xp.int32)[None, :] * v + xp.maximum(lab, 0)).reshape(-1)
+    landed = _scatter_min(xp, f * v, vcell, xp.where((accepted[:, None] & (lab >= 0)).reshape(-1), 0.0, RANK_INF))
+    out["ex_seen"] = xp.maximum(state["ex_seen"], (landed.reshape(f, v) == 0.0).astype(xp.float32))
+    return out
+
+
+# shape: (ps: dict, accepted: [P] bool, meta: dict, hard_pa: bool) -> [P] bool
+def pa_progress(xp, ps: dict, accepted, meta: dict, hard_pa: bool):
+    """[P] pod-affinity declarers worth keeping active though blocked
+    everywhere this round: some pending pod-affinity term gained a match
+    (per-term rows or family values), which can open nodes for them."""
+    declares = xp.zeros(accepted.shape, bool)
+    progressed = xp.bool_(False)
+    if hard_pa:
+        new_match = (ps["pod_pa_matched"] * accepted[:, None].astype(xp.float32)).sum(axis=0) > 0  # [Ta]
+        declares = declares | (ps["pod_pa_declares"].sum(axis=1) > 0)
+        progressed = progressed | new_match.any()
+    if "pod_ex_aff" in ps:
+        lab = ps["pod_ex_label"]
+        wanted = _per_family(xp, meta["ex_aff_declared"], lab) > 0
+        declares = declares | (ps["pod_ex_aff"] >= 0).any(axis=1)
+        progressed = progressed | (accepted[:, None] & (lab >= 0) & wanted).any()
+    return declares & progressed
 
 
 # shape: (accepted: [P] bool, choice: [P] i32, ps: dict, state: dict,
@@ -1333,6 +1695,8 @@ def constraint_commit(
     # commits (exactly when the pa_dom_m/pa_node_m sums leave zero: a
     # matched accepted pod lands in its domain when the node carries the
     # key, in its node row otherwise — either way the sum grows).
+    if "ex_m" in state:
+        out.update(_exclusive_commit(xp, accepted, choice, ps, state, meta))
     uses_sp = meta["sp_uses_dom"]
     sp_cell = sp_counts * uses_sp
     lo = xp.min(xp.where(uses_sp > 0, sp_cell, RANK_INF), axis=1)

@@ -70,6 +70,7 @@ __all__ = [
     "repack_avail",
     "repack_incremental",
     "extend_node_vocabs",
+    "pad_pods",
     "build_selector_vocab",
     "build_taint_vocab",
     "build_affinity_vocab",
@@ -221,6 +222,21 @@ class PackedCluster:
 
 
 # shape: (pods: obj) -> dict
+# shape: (packed: obj, p_pad: int) -> obj
+def pad_pods(packed: PackedCluster, p_pad: int) -> PackedCluster:
+    """``packed`` with its pod axis padded to ``p_pad`` rows, the new rows
+    zero and invalid as pack_snapshot's own padding is."""
+    extra = p_pad - packed.padded_pods
+    if extra <= 0:
+        return packed
+    grown = {
+        k: np.concatenate([v, np.zeros((extra,) + v.shape[1:], dtype=v.dtype)])
+        for k, v in packed.device_arrays().items()
+        if k.startswith("pod_")
+    }
+    return replace(packed, **grown)
+
+
 def build_selector_vocab(pods: list[Pod]) -> dict[tuple[str, str], int]:
     """Vocabulary of selector (key, value) pairs over the pending pods."""
     vocab: dict[tuple[str, str], int] = {}

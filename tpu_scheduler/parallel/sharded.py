@@ -27,6 +27,10 @@ the within-round filter + state commit run identically on every device over
 the already-gathered global claims.  No collectives beyond the two the
 unconstrained path already pays; determinism keeps the replicas in lockstep
 (same inputs → same state), exactly like the replicated ``avail`` columns.
+Exclusive families (ops/constraints.ExclusiveFamilies) are not run here:
+the backend does not claim ``supports_exclusive_families``, so the
+controller keeps those terms as per-term rows, and a constraint set that
+carries families raises UntensorizableConstraints.
 
 Per-round traffic: O(P) int32s over dp + O(P) over tp — a few MB at 100k
 pods, ICI-trivial next to the [P/dp × N/tp] compute tiles.
@@ -544,6 +548,13 @@ class ShardedBackend(SchedulingBackend):
             a[k] = np.pad(a[k], ((0, n_pad - packed.padded_nodes), (0, 0)))
         a["node_valid"] = np.pad(a["node_valid"], ((0, n_pad - packed.padded_nodes),))
         cons = packed.constraints
+        if cons is not None and cons.n_families:
+            # The mesh program has no exclusive-family operands; the
+            # controller never folds families for this backend
+            # (supports_exclusive_families), so only a direct caller lands here.
+            from ..ops.constraints import UntensorizableConstraints
+
+            raise UntensorizableConstraints(f"{cons.n_families} exclusive families: the sharded mesh does not run them")
         c = constraint_operands(cons, packed.padded_nodes, n_pad) if cons is not None else None
         soft_spread = cons is not None and cons.n_spread_soft > 0
         soft_pa = cons is not None and cons.n_ppa_terms > 0

@@ -38,7 +38,17 @@ from collections import OrderedDict
 from dataclasses import replace
 from itertools import chain, groupby
 
-from ..api.objects import Node, ObjectReference, Pod, PodResources, PodSpec, full_name, is_pod_bound, total_pod_resources
+from ..api.objects import (
+    Node,
+    ObjectReference,
+    Pod,
+    PodResources,
+    PodSpec,
+    exclusive_placement,
+    full_name,
+    is_pod_bound,
+    total_pod_resources,
+)
 from ..backends.base import SchedulingBackend
 from ..core.predicates import (
     INTER_POD_REASONS,
@@ -61,9 +71,9 @@ from ..core.predicates import (
 from ..core.snapshot import ClusterSnapshot, PlacedOverlay, node_allocatable, node_net_available, node_used_resources
 from ..errors import BackendUnavailable, CreateBindingFailed, NoNodeFound, SchedulerError
 from ..models.profiles import DEFAULT_PROFILE, SchedulingProfile
-from ..ops.pack import extend_node_vocabs, pack_snapshot, repack_incremental
+from ..ops.pack import extend_node_vocabs, pack_snapshot, pad_pods, repack_incremental
 from ..utils.events import SEGMENTS, FlightRecorder, waterfall
-from ..utils.metrics import CycleMetrics, MetricsRegistry, cycle_phases
+from ..utils.metrics import SUBPHASES, CycleMetrics, MetricsRegistry, cycle_phases
 from ..utils.profiler import (
     SLO_TIERS,
     ProfileRing,
@@ -325,8 +335,9 @@ class Scheduler:
         # match queries of constrained cycles the same way (self-clears on
         # term-vocabulary change; ops/constraints.pack_constraints).
         self._cons_memo: dict = {}
-        # Axis -> widest padding the constraint tensors have had, so a cycle
-        # with fewer distinct terms reuses the compiled shapes.
+        # Axis -> widest padding the constraint tensors (and the topology
+        # term's gang axis) have had, so a cycle with fewer distinct terms
+        # or gangs reuses the compiled shapes.
         self._cons_widths: dict[str, int] = {}
         # Pipelined binding (SURVEY.md §2b PP): the binding POSTs of cycle k
         # run on a worker thread while cycle k+1 syncs/packs/solves.  The
@@ -940,7 +951,9 @@ class Scheduler:
             return packed
         from ..topology.locality import pack_topology
 
-        topo = pack_topology(compiled, pending, packed.padded_pods, packed.node_names, packed.padded_nodes)
+        topo = pack_topology(
+            compiled, pending, packed.padded_pods, packed.node_names, packed.padded_nodes, widths=self._cons_widths
+        )
         if topo is None:
             return packed
         self.metrics.inc("scheduler_topology_cycles_total")
@@ -966,10 +979,27 @@ class Scheduler:
         carriers = [q for q, _ in snapshot.placed_pods_with_terms()] + [
             q for q in pending if q.spec is not None and q.spec.anti_affinity
         ]
-        term_list = [
-            (None, (q.metadata.namespace, t)) for q in carriers for t in q.spec.anti_affinity
-        ]
+        # JobSet's exclusive-placement terms (``K Exists, K NotIn [v]``) have
+        # no matchLabels to index; they go by (namespace, K) instead: a pod
+        # labelled K = x matches one unless every such term's v is x.
+        term_list = []
+        exclusive: dict[tuple, set] = {}
+        for q in carriers:
+            ns = q.metadata.namespace
+            for t in q.spec.anti_affinity:
+                shape = exclusive_placement(t)
+                if shape is not None and shape[0] == "aa":
+                    exclusive.setdefault((ns, shape[1]), set()).add(shape[2])
+                else:
+                    term_list.append((None, (ns, t)))
         probe, residual = _term_probe_index(term_list)
+
+        def exclusive_hit(ns, labels) -> bool:
+            for k, x in labels.items():
+                vs = exclusive.get((ns, k))
+                if vs and (len(vs) > 1 or x not in vs):
+                    return True
+            return False
 
         plain: list[Pod] = []
         constrained: list[Pod] = []
@@ -983,8 +1013,9 @@ class Scheduler:
             ):
                 constrained.append(p)
                 continue
-            hit = bool(
-                _matched_term_ids(term_list, probe, residual, p.metadata.namespace, p.metadata.labels or {})
+            labels = p.metadata.labels or {}
+            hit = (exclusive and exclusive_hit(p.metadata.namespace, labels)) or bool(
+                _matched_term_ids(term_list, probe, residual, p.metadata.namespace, labels)
             )
             (constrained if hit else plain).append(p)
         return plain, constrained
@@ -1218,6 +1249,7 @@ class Scheduler:
             _net_cache=snapshot._net_cache,
             _placed=snapshot._placed,
             _placed_with_terms=snapshot._placed_with_terms,
+            _exclusive=snapshot._exclusive,
         )
         object.__setattr__(view, "_pending", list(pending))
         return view
@@ -1648,7 +1680,7 @@ class Scheduler:
                 self.recorder.record_packed(
                     (full_name(p) for p in batch_snapshot.pending_pods()), self._cycle_tag, self.backend.name
                 )
-            packed = self._attach_topology(self._pack(batch_snapshot), batch_snapshot)
+            packed = self._pack(batch_snapshot)
             if with_constraints:
                 from ..ops.constraints import pack_constraints
 
@@ -1660,14 +1692,21 @@ class Scheduler:
                     packed.padded_nodes,
                     match_memo=self._cons_memo,
                     widths=self._cons_widths,
+                    exclusive=getattr(self.backend, "supports_exclusive_families", False),
                     **self.constraint_budgets,
                 )
+                self.metrics.set_gauge("scheduler_exclusive_families", cons.n_families if cons is not None else 0)
                 if cons is not None:
                     # Attached to a per-cycle copy only: the cached pack is
                     # reused incrementally, but domain state depends on the
-                    # cycle's placements and is rebuilt every time.
-                    packed = replace(packed, constraints=cons)
+                    # cycle's placements and is rebuilt every time.  A set
+                    # whose pod axis keeps a wider width grows the copy's.
+                    packed = replace(pad_pods(packed, cons.padded_pods), constraints=cons)
                     self.metrics.inc("scheduler_constraint_tensor_cycles_total")
+                    if cons.exclusive is not None:
+                        folded = len(cons.exclusive.folded_aa) + len(cons.exclusive.folded_pa)
+                        self.metrics.inc("scheduler_exclusive_terms_folded_total", folded)
+            packed = self._attach_topology(packed, batch_snapshot)
             if self._delta_plan is not None:
                 # Delta cycle, plain batch: drop node columns no dirty pod
                 # can land on (delta/repack.py — the PR-9 [A]-compaction
@@ -2603,6 +2642,7 @@ class Scheduler:
                 max(0.0, wall - sum(v for k, v in top.items() if k in phase_set)), 6
             ),
             **{f"{ph}_seconds": top.get(ph, 0.0) for ph in phase_set if ph != "other"},
+            **{name: trace.durations.get(path, 0.0) for name, path in SUBPHASES.items()},
         )
         self.metrics.observe_cycle(m)
         self.recorder.record_cycle(m.__dict__, trace.events, notes=self._cycle_notes)

@@ -26,8 +26,9 @@ from .api.objects import (
     TopologySpreadConstraint,
 )
 from .core.snapshot import ClusterSnapshot
+from .topology.model import GKE_NODEPOOL_KEY, GKE_TPU_TOPOLOGY_KEY
 
-__all__ = ["make_node", "make_pod", "synth_cluster"]
+__all__ = ["make_node", "make_pod", "synth_cluster", "jobset_fleet", "jobset_job", "jobset_terms", "tpu_slice_nodes"]
 
 # Node shapes roughly covering a heterogeneous fleet (cpu cores, memory GiB).
 _NODE_SHAPES = [(4, 16), (8, 32), (16, 64), (32, 128), (64, 256)]
@@ -374,3 +375,64 @@ def uneven_shard_scenario():
     cons = pack_constraints(snap, snap.pending_pods(), packed.padded_pods, packed.node_names, packed.padded_nodes)
     assert cons is not None, "scenario regressed: constraints no longer pack"
     return packed, _replace(packed, constraints=cons)
+
+
+# JobSet's job key — the exclusive-placement fleet of tests/test_exclusive_
+# families.py and the described-chip compile of tests/test_chip_compile.py,
+# on nodes with GKE's slice labels (topology/model.py).
+JOBSET_JOB_KEY = "jobset.sigs.k8s.io/job-key"
+TPU_RESOURCE = "google.com/tpu"
+
+
+def tpu_slice_nodes(pools: list[tuple[str, int]]) -> list[Node]:
+    """One node pool a slice, ``(topology, hosts)`` each: 4-chip hosts
+    labelled with their pool and topology, tainted ``google.com/tpu``."""
+    out = []
+    for s, (topo, hosts) in enumerate(pools):
+        for h in range(hosts):
+            out.append(make_node(f"slice-{s}-{h}", 112, "192Gi", {GKE_NODEPOOL_KEY: f"slice-{s}", GKE_TPU_TOPOLOGY_KEY: topo},
+                                 [Taint(key=TPU_RESOURCE, value="present", effect="NoSchedule")], extended={TPU_RESOURCE: "4"}))
+    return out
+
+
+def jobset_terms(job: str) -> tuple[list[PodAntiAffinityTerm], list[PodAntiAffinityTerm]]:
+    """(pod affinity, anti-affinity) JobSet's exclusive placement over the
+    node-pool key gives every pod of ``job``."""
+    affinity = [PodAntiAffinityTerm(topology_key=GKE_NODEPOOL_KEY, match_expressions=[LabelSelectorRequirement(JOBSET_JOB_KEY, "In", [job])])]
+    anti = [PodAntiAffinityTerm(topology_key=GKE_NODEPOOL_KEY, match_expressions=[
+        LabelSelectorRequirement(JOBSET_JOB_KEY, "Exists"), LabelSelectorRequirement(JOBSET_JOB_KEY, "NotIn", [job])])]
+    return affinity, anti
+
+
+def jobset_job(job: str, topo: str, hosts: int, priority: int = 0, on: list[str] | None = None, gang: bool = True) -> list[Pod]:
+    """One JobSet job: a pod a host, 4 chips each, selecting its slice
+    topology, with the exclusive-placement terms; bound to ``on`` if given."""
+    affinity, anti = jobset_terms(job)
+    return [
+        make_pod(f"{job}-{i}", cpu="8", memory="32Gi", node_name=on[i] if on else None,
+                 phase="Running" if on else "Pending", priority=priority, labels={JOBSET_JOB_KEY: job},
+                 extended={TPU_RESOURCE: "4"}, node_selector={GKE_TPU_TOPOLOGY_KEY: topo},
+                 tolerations=[Toleration(key=TPU_RESOURCE, operator="Exists", effect="NoSchedule")],
+                 pod_affinity=affinity, anti_affinity=anti, gang=job if gang else None)
+        for i in range(hosts)
+    ]
+
+
+def jobset_fleet(census: dict[str, int], occupied: float, pending_jobs: int, seed: int) -> ClusterSnapshot:
+    """A fleet of TPU slice pools (``census``: topology -> pools, ``"4x4"``
+    being 4 hosts), ``occupied`` of each topology's pools running a job,
+    and ``pending_jobs`` jobs waiting, topologies drawn by the census."""
+    rng = random.Random(seed)
+    pools = [(topo, int(topo.split("x")[0]) * int(topo.split("x")[1]) // 4) for topo, n in census.items() for _ in range(n)]
+    pods: list[Pod] = []
+    j = 0
+    for topo in census:
+        idx = [s for s, (t, _h) in enumerate(pools) if t == topo]
+        for s in rng.sample(idx, int(len(idx) * occupied)):
+            hosts = pools[s][1]
+            pods += jobset_job(f"run-{j}", topo, hosts, rng.randrange(3), [f"slice-{s}-{h}" for h in range(hosts)])
+            j += 1
+    for k in range(pending_jobs):
+        topo = rng.choices(list(census), list(census.values()))[0]
+        pods += jobset_job(f"new-{k}", topo, dict(pools)[topo], rng.randrange(3))
+    return ClusterSnapshot.build(tpu_slice_nodes(pools), pods)

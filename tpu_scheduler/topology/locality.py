@@ -105,7 +105,8 @@ class TopologySet:
         node index n for pods with no accepted choice), row 0 the no-gang
         dump — both never read back."""
         n = self.meta["dom_id_0"].shape[0]
-        return {"gang_nodes": np.zeros((self.n_gangs + 1, n + 1), dtype=np.float32)}
+        rows = self.meta["gang_tb_0"].shape[0]  # n_gangs + 1, or the wider padded width
+        return {"gang_nodes": np.zeros((rows, n + 1), dtype=np.float32)}
 
 
 # shape: (gang: str, level: int, dom: int) -> float
@@ -115,13 +116,19 @@ def _herd_tb(gang: str, level: int, dom: int) -> float:
     return zlib.crc32(f"{gang}|{level}|{dom}".encode()) / 4294967296.0
 
 
-# shape: (compiled: obj, pending: obj, p_pad: int, node_names: obj, n_pad: int) -> obj
-def pack_topology(compiled, pending, p_pad: int, node_names: tuple[str, ...], n_pad: int) -> TopologySet | None:
+# shape: (compiled: obj, pending: obj, p_pad: int, node_names: obj, n_pad: int,
+#   widths: dict) -> obj
+def pack_topology(
+    compiled, pending, p_pad: int, node_names: tuple[str, ...], n_pad: int, widths: dict | None = None
+) -> TopologySet | None:
     """Build the cycle's TopologySet, or None when no pending pod declares a
     gang (the term would be all-zero; skipping keeps gangless cycles free).
 
     ``compiled`` node order must cover ``node_names`` (same snapshot);
-    padding rows/columns get gang 0 / per-level sentinel domains."""
+    padding rows/columns get gang 0 / per-level sentinel domains.
+    ``widths`` (caller-owned, as ops/constraints.pack_constraints' is) keeps
+    the gang axis at the widest it has had, so a cycle with fewer gangs
+    reuses the compiled programs; the extra gang rows are never named."""
     gang_ids = np.zeros((p_pad,), dtype=np.int32)
     gang_names: list[str] = []
     by_name: dict[str, int] = {}
@@ -141,6 +148,8 @@ def pack_topology(compiled, pending, p_pad: int, node_names: tuple[str, ...], n_
     gather = np.asarray([row[n] for n in node_names], dtype=np.intp)
     n_real = len(node_names)
     g1 = len(gang_names) + 1
+    if widths is not None:
+        g1 = widths["gangs"] = max(g1, widths.get("gangs", 0))
     meta: dict[str, np.ndarray] = {"level_dist": compiled.level_distances()}
     for l_idx in range(compiled.n_levels):
         d = int(compiled.dom_counts[l_idx])

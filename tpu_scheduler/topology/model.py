@@ -48,6 +48,15 @@ DEFAULT_LEVEL_KEYS = (
     ("rack", "topology.tpu-scheduler/rack"),
 )
 
+# GKE's own labels for a multi-host TPU slice: every slice is a node pool,
+# and its nodes carry the slice topology (cloud.google.com/kubernetes-engine
+# /docs/concepts/tpus).  A node carrying GKE_TPU_TOPOLOGY_KEY is a member of
+# the slice named by its GKE_NODEPOOL_KEY; other nodes of the cluster (CPU
+# pools) are in no slice.  Read where no node carries the repo's own slice
+# label, so a cluster labelled both ways keeps its explicit slices.
+GKE_NODEPOOL_KEY = "cloud.google.com/gke-nodepool"
+GKE_TPU_TOPOLOGY_KEY = "cloud.google.com/gke-tpu-topology"
+
 
 @dataclass(frozen=True)
 class TopologyLevel:
@@ -58,6 +67,16 @@ class TopologyLevel:
     name: str
     key: str | None = None
     distance: float = 1.0
+    # When set, ``key`` names a node's domain only on nodes that also carry
+    # ``member_key`` (GKE's slice level: the pool key, on TPU nodes alone).
+    member_key: str | None = None
+
+    # shape: (labels: dict) -> obj
+    def domain_of(self, labels: dict) -> str | None:
+        """This level's domain in a node's labels, or None."""
+        if self.key is None or (self.member_key is not None and self.member_key not in labels):
+            return None
+        return labels.get(self.key)
 
 
 @dataclass(frozen=True)
@@ -81,18 +100,27 @@ class TopologyModel:
         """Auto-detection for ``--topology auto``: a model over the default
         label keys, or None when NO node advertises any of them — an
         unlabeled cluster stays topology-blind instead of degenerating to
-        per-node singleton domains."""
+        per-node singleton domains.  Where no node carries the slice key but
+        some carry GKE's TPU-topology label, the slice level is GKE's node
+        pool, on those nodes alone."""
+        levels = [TopologyLevel(name=n, key=k) for n, k in level_keys]
         present = set()
+        gke = False
         for node in nodes:
             labels = node.metadata.labels or {}
-            for name, key in level_keys:
-                if key in labels:
-                    present.add(name)
+            for lv in levels:
+                if lv.key in labels:
+                    present.add(lv.name)
+            gke = gke or (GKE_TPU_TOPOLOGY_KEY in labels and GKE_NODEPOOL_KEY in labels)
+        if "slice" not in present and gke and any(lv.name == "slice" for lv in levels):
+            present.add("slice")
+            levels = [
+                TopologyLevel(name="slice", key=GKE_NODEPOOL_KEY, member_key=GKE_TPU_TOPOLOGY_KEY) if lv.name == "slice" else lv
+                for lv in levels
+            ]
         if not present:
             return None
-        return TopologyModel(
-            levels=tuple(TopologyLevel(name=n, key=k) for n, k in level_keys if n in present)
-        )
+        return TopologyModel(levels=tuple(lv for lv in levels if lv.name in present))
 
     # shape: (spec: dict) -> obj
     @staticmethod
@@ -136,8 +164,8 @@ class TopologyModel:
             for i, node in enumerate(nodes):
                 spec_doms = self.node_domains.get(node.metadata.name)
                 dom = spec_doms.get(lv.name) if spec_doms else None
-                if dom is None and lv.key is not None:
-                    dom = (node.metadata.labels or {}).get(lv.key)
+                if dom is None:
+                    dom = lv.domain_of(node.metadata.labels or {})
                 if dom is None:
                     dom = f"~{node.metadata.name}"
                 if dom not in vocab:

@@ -125,7 +125,7 @@ class CycleMetrics:
     # scan), queue (eligibility + snapshot rebuild + gang census),
     # constrained (host sequential fallback), preempt, gang (admission
     # accounting), slo (pending-age tracker).  Every field here except
-    # wall/other MUST correspond to a depth-0 span name — cycle_phases()
+    # wall/other and the SUBPHASES MUST correspond to a depth-0 span name — cycle_phases()
     # derives the set, observe_cycle and the controller's breakdown both
     # consume it, and tests/test_profiler.py pins the exact match so a new
     # phase cannot silently land in `other`.
@@ -149,6 +149,10 @@ class CycleMetrics:
     # so elastic-capacity cost can never hide in `other`.
     autoscale_seconds: float = 0.0
     other_seconds: float = 0.0  # wall minus every attributed phase
+    # Nested cost centres read on their own (SUBPHASES: field -> span path).
+    # Their time is inside a phase above, so they are no phase: they take
+    # no part in ``other`` and the breakdown's sum.
+    exclusive_seconds: float = 0.0  # pack/exclusive: the exclusive families' state
 
     @property
     def pods_per_second(self) -> float:
@@ -160,16 +164,20 @@ class CycleMetrics:
         return json.dumps(d)
 
 
+# CycleMetrics fields that read a nested span (field name -> span path).
+SUBPHASES = {"exclusive_seconds": "pack/exclusive"}
+
+
 def cycle_phases() -> tuple[str, ...]:
     """The closed cycle-phase set, DERIVED from the CycleMetrics fields
-    (every ``*_seconds`` field except ``wall``): the one source the
-    ``scheduler_phase_seconds{phase=}`` series, the controller's breakdown
-    construction, and the drift test all share — adding a phase field wires
-    the metric and the other-subtraction automatically."""
+    (every ``*_seconds`` field except ``wall`` and the SUBPHASES): the one
+    source the ``scheduler_phase_seconds{phase=}`` series, the controller's
+    breakdown construction, and the drift test all share — adding a phase
+    field wires the metric and the other-subtraction automatically."""
     return tuple(
         f.name[: -len("_seconds")]
         for f in fields(CycleMetrics)
-        if f.name.endswith("_seconds") and f.name != "wall_seconds"
+        if f.name.endswith("_seconds") and f.name != "wall_seconds" and f.name not in SUBPHASES
     )
 
 

@@ -101,6 +101,7 @@ SPAN_CATALOGUE = (
     "pa",          # filter sub-span: positive-affinity bootstrap min-rank
     "spread",      # filter sub-span: spread rank-prefix admission + cascade
     "commit",      # choose sub-span: domain-state commit of accepted claims
+    "exclusive",   # pack sub-span: exclusive-placement families' state (ops/constraints.py)
     "post",        # bind sub-span: the API server's binding calls, summed per bind phase
     "explain",     # bind/mopup/constrained sub-span: the why-pending explainer, summed per phase
     "snapshot",    # rebalance sub-span: consistent packing-view build
